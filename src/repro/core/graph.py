@@ -104,9 +104,10 @@ class BlockELL(NamedTuple):
         prefix of each row, of length ``live_w[row]``;
       * ``live_w`` is int32[num_blocks * block_rows] (padded rows included,
         with ``live_w == 0``); ``num_rows`` is the *logical* row count;
-      * ``val``/``col`` may carry >= ``max_width`` zeroed elements past
-        ``total_slots`` (the stitcher appends them) so the block kernel's
-        fixed-size row DMA can over-read safely without a per-call pad.
+      * ``val``/``col`` may carry ``kernels.gather.block_tail(max_width)``
+        zeroed elements past ``total_slots`` (the stitcher appends them)
+        so the block kernel's fixed-size staging DMA can over-read safely
+        without a per-call pad.
     """
 
     val: jax.Array              # f32[total_slots]  flattened block segments

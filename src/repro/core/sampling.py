@@ -284,12 +284,14 @@ def sample_csr_to_block_ell(csr, configs, block_rows: int):
         widths.append(w)
         strategies.append(s)
 
-    # Trailing max-width zero pad: lets the block kernel's fixed-size row
-    # DMA read past the last segment without a per-request jnp.pad copy
+    # Trailing zero pad: lets the block kernel's fixed-size staging DMA
+    # read past the last segment without a per-request jnp.pad copy
     # (serving hits run straight off this operand).
-    max_w = max(widths)
-    vals.append(jnp.zeros(max_w, csr.val.dtype))
-    cols.append(jnp.zeros(max_w, jnp.int32))
+    from repro.kernels.gather import block_tail
+
+    tail = block_tail(max(widths))
+    vals.append(jnp.zeros(tail, csr.val.dtype))
+    cols.append(jnp.zeros(tail, jnp.int32))
     bell = BlockELL(
         val=jnp.concatenate(vals), col=jnp.concatenate(cols),
         live_w=jnp.concatenate(lives), widths=tuple(widths),
@@ -303,7 +305,7 @@ def sample_csr_to_block_ell(csr, configs, block_rows: int):
         obs.count("sampler.block_calls")
         obs.count("sampler.edges_kept", kept)
         obs.count("sampler.edges_dropped", max(int(csr.nnz) - kept, 0))
-        obs.count("sampler.block_slots", int(bell.col.size) - max_w)
+        obs.count("sampler.block_slots", bell.total_slots)
     return bell
 
 

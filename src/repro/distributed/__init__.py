@@ -1,10 +1,9 @@
-from repro.distributed.mesh_compat import abstract_mesh
 from repro.distributed.serving import (SHARD_AXIS, serving_mesh,
                                        shard_devices)
 from repro.distributed.sharding import (batch_shardings, cache_shardings,
                                         dp_axes, opt_shardings,
                                         param_shardings)
 
-__all__ = ["SHARD_AXIS", "abstract_mesh", "batch_shardings",
+__all__ = ["SHARD_AXIS", "batch_shardings",
            "cache_shardings", "dp_axes", "opt_shardings", "param_shardings",
            "serving_mesh", "shard_devices"]

@@ -35,7 +35,10 @@ def serving_mesh(num_shards: int):
             f"only {avail} exist; use the per-shard launch loop "
             "(shard_devices) or force host devices via XLA_FLAGS="
             f"--xla_force_host_platform_device_count={num_shards}")
-    return jax.make_mesh((num_shards,), (SHARD_AXIS,))
+    # Auto axis: shardings propagate as in jit, so eager indexing of a
+    # sharded result (the engine's ragged-tail trim) needs no out_sharding
+    return jax.make_mesh((num_shards,), (SHARD_AXIS,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def shard_devices(num_shards: int, devices=None) -> list:

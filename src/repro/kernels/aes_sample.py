@@ -5,8 +5,9 @@ sample once and reuse the ELL across layers (both GCN layers aggregate with
 the same A, so sampling once amortizes — the paper's kernel resamples per
 call; this is a beyond-paper amortization, see EXPERIMENTS.md §Perf).
 
-Output tiles are the same ``sh_val/sh_col`` staging the fused kernel keeps
-in VMEM scratch, but written out to HBM in ELL layout.
+Each row is sampled by the same ``sample_row`` the fused kernel runs; the
+staged slots land in SMEM output tiles and are written out to HBM in ELL
+layout.
 """
 from __future__ import annotations
 
@@ -15,56 +16,40 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.pallas_compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.sampling import PRIME_NUM
-
-from .fused_spmm import _strategy_scalar
+from .fused_spmm import sample_row
+from .gather import flat_window
 
 
 def _sample_kernel(rs_ref, nnz_ref, ci_ref, av_ref, val_out, col_out,
                    stage_i, stage_f, sem, *, sh_width: int):
-    block_r = rs_ref.shape[0]
+    """grid = (row_tiles,).
 
-    def row_body(r, _):
-        row_start = rs_ref[r, 0]
-        row_nnz = nnz_ref[r, 0]
-        W, N, cnt = _strategy_scalar(row_nnz, sh_width)
-        span = jnp.maximum(row_nnz - N + 1, 1)
+    rs_ref/nnz_ref:   i32[1, block_r]        SMEM  CSR row starts / row nnz
+    ci_ref/av_ref:    HBM  full CSR col_ind / val arrays (padded)
+    val_out/col_out:  [block_r, sh_width]    SMEM  the sampled ELL rows,
+        written slot by slot with scalar stores and copied out whole by the
+        output pipeline
+    stage_i/stage_f:  SMEM  aligned landing windows of one sample's CSR run
+    """
+    def row_body(r, carry):
+        def zero(j, c):
+            val_out[r, j] = jnp.float32(0)
+            col_out[r, j] = jnp.int32(0)
+            return c
 
-        pl.store(val_out, (pl.ds(r, 1), slice(None)),
-                 jnp.zeros((1, sh_width), jnp.float32))
-        pl.store(col_out, (pl.ds(r, 1), slice(None)),
-                 jnp.zeros((1, sh_width), jnp.int32))
+        jax.lax.fori_loop(0, sh_width, zero, 0)
 
-        def sample_body(i, _):
-            start = (i * PRIME_NUM) % span
-            cp_i = pltpu.make_async_copy(
-                ci_ref.at[pl.ds(row_start + start, sh_width)], stage_i, sem.at[0])
-            cp_i.start()
-            cp_i.wait()
-            cp_f = pltpu.make_async_copy(
-                av_ref.at[pl.ds(row_start + start, sh_width)], stage_f, sem.at[0])
-            cp_f.start()
-            cp_f.wait()
+        def put(slot, c, v):
+            col_out[r, slot] = c
+            val_out[r, slot] = v
 
-            def elem_body(j, _):
-                slot = i + j * cnt
-                pl.store(col_out, (pl.ds(r, 1), pl.ds(slot, 1)),
-                         stage_i[j].reshape(1, 1))
-                pl.store(val_out, (pl.ds(r, 1), pl.ds(slot, 1)),
-                         stage_f[j].reshape(1, 1))
-                return _
+        sample_row(ci_ref, av_ref, stage_i, stage_f, sem, rs_ref[0, r],
+                   nnz_ref[0, r], sh_width, put)
+        return carry
 
-            jax.lax.fori_loop(0, jnp.minimum(N, sh_width), elem_body, None)
-            return _
-
-        @pl.when(row_nnz > 0)
-        def _():
-            jax.lax.fori_loop(0, cnt, sample_body, None)
-        return _
-
-    jax.lax.fori_loop(0, block_r, row_body, None)
+    jax.lax.fori_loop(0, val_out.shape[0], row_body, 0)
 
 
 @functools.partial(
@@ -73,38 +58,44 @@ def aes_sample(row_start, row_nnz, col_ind, val, *, sh_width: int,
                block_r: int = 8, interpret: bool = True):
     """Returns (ell_val, ell_col) of shape [rows, sh_width].
 
-    ``col_ind``/``val`` must carry >= sh_width padding elements at the end
-    (the fixed-size sample DMA may over-read past a row's end; over-read
-    values are masked by the slot layout, padding only prevents OOB).
+    ``col_ind``/``val`` must carry >= ``flat_window(sh_width)`` padding
+    elements at the end (the fixed-size sample DMA over-reads past a row's
+    end; over-read values are masked by the slot layout, padding only
+    prevents OOB).
     """
     rows = row_start.shape[0]
     assert rows % block_r == 0
     kernel = functools.partial(_sample_kernel, sh_width=sh_width)
+    stage = flat_window(sh_width)
     return pl.pallas_call(
         kernel,
         grid=(rows // block_r,),
         in_specs=[
-            pl.BlockSpec((block_r, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_r, 1), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec((None, 1, block_r), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, 1, block_r), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((block_r, sh_width), lambda i: (i, 0)),
-            pl.BlockSpec((block_r, sh_width), lambda i: (i, 0)),
+            pl.BlockSpec((block_r, sh_width), lambda i: (i, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((block_r, sh_width), lambda i: (i, 0),
+                         memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, sh_width), jnp.float32),
             jax.ShapeDtypeStruct((rows, sh_width), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((sh_width,), jnp.int32),
-            pltpu.VMEM((sh_width,), jnp.float32),
+            pltpu.SMEM((stage,), jnp.int32),
+            pltpu.SMEM((stage,), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-    )(row_start.reshape(rows, 1).astype(jnp.int32),
-      row_nnz.reshape(rows, 1).astype(jnp.int32),
+    )(row_start.astype(jnp.int32).reshape(rows // block_r, 1, block_r),
+      row_nnz.astype(jnp.int32).reshape(rows // block_r, 1, block_r),
       col_ind, val)

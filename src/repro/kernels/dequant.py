@@ -12,7 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.pallas_compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 
 def dequant_epilogue(q, scale, x_min, dtype=jnp.float32):
@@ -20,9 +20,10 @@ def dequant_epilogue(q, scale, x_min, dtype=jnp.float32):
 
     Shared by this standalone kernel and the fused-dequant gathers in
     ``ell_spmm.py`` (both the fixed-width and the block-dispatched SpMM),
-    so the dequantization math has exactly one home.
+    so the dequantization math has exactly one home.  Mosaic converts
+    unsigned integers to float only through int32.
     """
-    return q.astype(dtype) * scale + x_min
+    return q.astype(jnp.int32).astype(dtype) * scale + x_min
 
 
 def _dequant_kernel(q_ref, out_ref, *, scale: float, x_min: float):

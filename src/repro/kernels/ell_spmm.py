@@ -1,16 +1,15 @@
 """Pallas TPU kernels: SpMM over the AES-sampled ELL layout, plus the
 block-dispatched variant over the mixed-width BlockELL layout.
 
-This is the SpMM stage of Algorithm 1 (lines 16-19), re-thought for TPU
-(DESIGN.md §2):
+This is the SpMM stage of Algorithm 1 (lines 16-19), re-thought for TPU:
 
-  * the sampled ``(val, col)`` tiles are staged in **VMEM** by ``BlockSpec``
-    — the analogue of the paper's shared-memory staging;
-  * the dense feature matrix B stays in **HBM** (``MemorySpace.ANY``); each
-    referenced row slice is DMA'd into a VMEM scratch buffer with
-    ``pltpu.make_async_copy`` (the analogue of the GPU's global-memory
-    fetch ``B[sh_col[k], cid]``), double-buffered so the copy of row k+1
-    overlaps the FMA of row k;
+  * the sampled ``(val, col)`` tiles are staged in **SMEM** by ``BlockSpec``
+    — the analogue of the paper's shared-memory staging, and the memory a
+    DMA address must come from;
+  * the dense feature matrix B stays in **HBM** (``pl.ANY``), feature-tile
+    major; each referenced row is DMA'd into a VMEM landing zone, double
+    buffered so the copy of row k+1 overlaps the FMA of row k (the shared
+    core in ``gather.py``);
   * one Pallas program per (row-tile x feature-tile) replaces one CUDA
     thread per output element; the per-row ``k in [0, live_w)`` loop is the
     paper's ``for k <- 0 to W`` with the same dynamic bound
@@ -31,60 +30,40 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.dequant import dequant_epilogue
-from repro.kernels.pallas_compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.gather import (FLAT_TILE, feature_tiles, flat_window,
+                                  for_row_groups, gather_accumulate,
+                                  lane_pack, row_group, stage_flat,
+                                  stage_rows, vmem_limit)
 
 
-def _ell_spmm_kernel(val_ref, col_ref, live_ref, b_ref, out_ref,
-                     scratch, sem, *, block_f: int, quantized: bool,
-                     scale: float, x_min: float):
+def _ell_spmm_kernel(val_ref, col_ref, live_ref, b_ref, out_ref, bsc, sem,
+                     *, pack: int, bits, scale: float, x_min: float):
     """grid = (row_tiles, feat_tiles).
 
-    val_ref:  f32[block_r, W]   VMEM   sampled edge weights
-    col_ref:  i32[block_r, W]   VMEM   sampled column indices
-    live_ref: i32[block_r, 1]   VMEM   live width per row (= min(nnz, W))
-    b_ref:    [num_nodes, F]    HBM    dense features (f32, or uint8 if quantized)
-    out_ref:  f32[block_r, block_f] VMEM
-    scratch:  [2, 1, block_f]   VMEM   double-buffered B-row landing zone
+    val_ref:  f32[block_r, W]   SMEM   sampled edge weights
+    col_ref:  i32[block_r, W]   SMEM   sampled column indices
+    live_ref: i32[1, block_r]   SMEM   live width per row (= min(nnz, W))
+    b_ref:    [tiles, num_nodes, block_f] HBM  dense features, f32 or
+              quantized ones packed ``pack`` to a word (``feature_tiles``)
+    out_ref:  f32[block_r, pack * block_f] VMEM
+    bsc:      [2, 1, 1, block_f] VMEM   double-buffered B-row landing zone
     sem:      DMA semaphores [2]
     """
-    f_tile = pl.program_id(1)
-    f_start = f_tile * block_f
-    block_r = val_ref.shape[0]
+    b_tile = b_ref.at[pl.ds(pl.program_id(1), 1)]
 
-    def b_row_copy(c, slot):
-        return pltpu.make_async_copy(
-            b_ref.at[pl.ds(c, 1), pl.ds(f_start, block_f)],
-            scratch.at[slot],
-            sem.at[slot],
-        )
+    def row_acc(base, i):
+        r = base + i
+        return gather_accumulate(
+            b_tile, bsc, sem, live_ref[0, r], lambda k: col_ref[r, k],
+            lambda k: val_ref[r, k], pack=pack, bits=bits, scale=scale,
+            x_min=x_min)
 
-    def row_body(r, _):
-        live_w = live_ref[r, 0]
+    def store(base, tile):
+        out_ref[pl.ds(base, tile.shape[0]), :] = tile
 
-        @pl.when(live_w > 0)
-        def _():
-            b_row_copy(col_ref[r, 0], 0).start()
-
-        def k_body(k, acc):
-            slot = jax.lax.rem(k, 2)
-
-            @pl.when(k + 1 < live_w)
-            def _():
-                b_row_copy(col_ref[r, k + 1], jax.lax.rem(k + 1, 2)).start()
-
-            b_row_copy(col_ref[r, k], slot).wait()
-            row = scratch[slot, 0, :]
-            if quantized:
-                row = dequant_epilogue(row, scale, x_min)
-            return acc + val_ref[r, k] * row
-
-        acc = jax.lax.fori_loop(
-            0, live_w, k_body, jnp.zeros((block_f,), jnp.float32))
-        pl.store(out_ref, (pl.ds(r, 1), slice(None)), acc[None, :])
-        return _
-
-    jax.lax.fori_loop(0, block_r, row_body, None)
+    for_row_groups(out_ref.shape[0], row_acc, store)
 
 
 @functools.partial(
@@ -96,37 +75,45 @@ def ell_spmm(ell_val, ell_col, live_w, b, *, block_r: int = 8,
              scale=1.0, x_min=0.0, interpret: bool = True):
     """C[r, :] = sum_k ell_val[r, k] * B[ell_col[r, k], :].
 
-    Inputs must be padded: rows % block_r == 0, feat % block_f == 0
-    (``repro.kernels.ops`` handles padding).
+    Inputs must be padded: rows % block_r == 0, feat % (pack * block_f)
+    == 0 with ``pack = lane_pack(b.dtype, feat, block_f)``
+    (``repro.kernels.ops`` pads).  A quantized B (``quantized=True``:
+    uint8/uint16) covers ``pack * block_f`` features per program.
     """
     rows, width = ell_val.shape
     feat = b.shape[1]
-    assert rows % block_r == 0 and feat % block_f == 0
+    pack = lane_pack(b.dtype, feat, block_f)
+    tile = pack * block_f
+    assert rows % block_r == 0 and feat % tile == 0
+    bt = feature_tiles(b, block_f, pack)
 
-    grid = (rows // block_r, feat // block_f)
-    scratch_dtype = b.dtype
+    grid = (rows // block_r, feat // tile)
     kernel = functools.partial(
-        _ell_spmm_kernel, block_f=block_f, quantized=quantized,
-        scale=scale, x_min=x_min)
+        _ell_spmm_kernel, pack=pack, scale=scale, x_min=x_min,
+        bits=8 * b.dtype.itemsize if quantized else None)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_r, width), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_r, width), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_r, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec((block_r, width), lambda i, j: (i, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((block_r, width), lambda i, j: (i, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, 1, block_r), lambda i, j: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((block_r, block_f), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((block_r, tile), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, feat), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((2, 1, block_f), scratch_dtype),
+            pltpu.VMEM((2, 1, 1, block_f), bt.dtype),
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-    )(ell_val, ell_col, live_w.reshape(rows, 1).astype(jnp.int32), b)
+    )(ell_val, ell_col,
+      live_w.astype(jnp.int32).reshape(rows // block_r, 1, block_r), bt)
 
 
 # ---------------------------------------------------------------------------
@@ -135,76 +122,53 @@ def ell_spmm(ell_val, ell_col, live_w, b, *, block_r: int = 8,
 
 def _block_ell_spmm_kernel(table_ref, live_ref, val_ref, col_ref, b_ref,
                            out_ref, stage_v, stage_c, bsc, ssem, bsem,
-                           *, block_f: int, max_w: int, block_rows: int,
-                           quantized: bool, scale: float, x_min: float):
+                           *, max_w: int, pack: int, bits, scale: float,
+                           x_min: float):
     """grid = (num_blocks, feat_tiles) — one program per (row block x F tile).
 
-    table_ref: i32[1, 2]          VMEM  this block's (slot offset, width)
-    live_ref:  i32[block_rows, 1] VMEM  live slots per row
-    val_ref:   f32[slots + max_w] HBM   flattened mixed-width segments
-    col_ref:   i32[slots + max_w] HBM
-    b_ref:     [num_nodes, F]     HBM   dense features (f32, or the quantized
-        storage dtype when ``quantized`` — Eq. 2 fuses into the gather)
-    out_ref:   f32[block_rows, block_f] VMEM
-    stage_v/stage_c: VMEM[max_w]  row-slot landing zones (one DMA per row,
-        maximal static size; the live_w bound masks the tail)
-    bsc:       VMEM[2, 1, block_f] double-buffered B-row landing zone
+    table_ref: i32[1, 2]          SMEM  this block's (slot offset, width)
+    live_ref:  i32[1, block_rows] SMEM  live slots per row
+    val_ref:   f32[slots + tail]  HBM   flattened mixed-width segments
+    col_ref:   i32[slots + tail]  HBM
+    b_ref:     [tiles, num_nodes, block_f] HBM  dense features, f32 or
+        quantized ones packed ``pack`` to a word (Eq. 2 fuses into the
+        gather)
+    out_ref:   f32[block_rows, pack * block_f] VMEM
+    stage_v/stage_c: SMEM  slot landing zones: the aligned window over
+        the ``max_w`` slots of each row of a row group (of one row, when
+        the group's window would crowd SMEM), one DMA at a static size;
+        the live_w bound masks the tail
+    bsc:       VMEM[2, 1, 1, block_f] double-buffered B-row landing zone
 
     Each program reads its own width from the block table.  The economy of
     a narrow tail block is in its accumulation loop (live_w-bounded) and
-    its HBM footprint (narrow flat segments); the row staging DMA is
-    ``max_w`` wide — Pallas copy sizes are static, so the ops wrapper
-    groups blocks into *width buckets* and issues one launch per bucket
-    with ``max_w`` = that bucket's widest block, keeping narrow blocks off
+    its HBM footprint (narrow flat segments); the staging DMA is sized by
+    ``max_w`` — Pallas copy sizes are static, so the ops wrapper groups
+    blocks into *width buckets* and issues one launch per bucket with
+    ``max_w`` = that bucket's widest block, keeping narrow blocks off
     max-width DMAs.
     """
-    f_start = pl.program_id(1) * block_f
+    b_tile = b_ref.at[pl.ds(pl.program_id(1), 1)]
     seg_off = table_ref[0, 0]
     width = table_ref[0, 1]
+    per = stage_rows(row_group(out_ref.shape[0]), max_w)
 
-    def row_body(r, _):
-        live = live_ref[r, 0]
-        row_slot = seg_off + r * width
+    def row_acc(base, i):
+        j = i % per                         # row within the staged rows
+        start = seg_off + (base + i - j) * width
+        if j == 0:
+            stage_flat(((val_ref, stage_v), (col_ref, stage_c)), ssem,
+                       start, per * max_w)
+        off = start % FLAT_TILE + j * width
+        return gather_accumulate(
+            b_tile, bsc, bsem, live_ref[0, base + i],
+            lambda k: stage_c[off + k], lambda k: stage_v[off + k],
+            pack=pack, bits=bits, scale=scale, x_min=x_min)
 
-        # val and col staging use separate buffers + semaphores: issue both
-        # DMAs before waiting so the two copies overlap.
-        cp_v = pltpu.make_async_copy(
-            val_ref.at[pl.ds(row_slot, max_w)], stage_v, ssem.at[0])
-        cp_c = pltpu.make_async_copy(
-            col_ref.at[pl.ds(row_slot, max_w)], stage_c, ssem.at[1])
-        cp_v.start()
-        cp_c.start()
-        cp_v.wait()
-        cp_c.wait()
+    def store(base, tile):
+        out_ref[pl.ds(base, tile.shape[0]), :] = tile
 
-        def b_copy(c, slot):
-            return pltpu.make_async_copy(
-                b_ref.at[pl.ds(c, 1), pl.ds(f_start, block_f)],
-                bsc.at[slot], bsem.at[slot])
-
-        @pl.when(live > 0)
-        def _():
-            b_copy(pl.load(stage_c, (jnp.int32(0),)), 0).start()
-
-        def k_body(k, acc):
-            slot = jax.lax.rem(k, 2)
-
-            @pl.when(k + 1 < live)
-            def _():
-                b_copy(pl.load(stage_c, (k + 1,)), jax.lax.rem(k + 1, 2)).start()
-
-            b_copy(pl.load(stage_c, (k,)), slot).wait()
-            row = bsc[slot, 0, :]
-            if quantized:
-                row = dequant_epilogue(row, scale, x_min)
-            return acc + pl.load(stage_v, (k,)) * row
-
-        acc = jax.lax.fori_loop(0, live, k_body,
-                                jnp.zeros((block_f,), jnp.float32))
-        pl.store(out_ref, (pl.ds(r, 1), slice(None)), acc[None, :])
-        return _
-
-    jax.lax.fori_loop(0, block_rows, row_body, None)
+    for_row_groups(out_ref.shape[0], row_acc, store)
 
 
 @functools.partial(
@@ -222,11 +186,12 @@ def block_ell_spmm(table, live_w, val_flat, col_flat, b, *, block_rows: int,
         With width bucketing the ops wrapper passes only one bucket's
         blocks here; the launch is then ``max_w``-wide for exactly those.
       live_w: i32[num_blocks * block_rows] live slots per row.
-      val_flat / col_flat: flattened segments, padded by >= ``max_w``
-        trailing elements so the fixed-size row DMA never over-reads
-        (``repro.kernels.ops.block_ell_spmm`` pads).
-      b: dense operand [num_nodes, feat]; feat % block_f == 0.  f32, or the
-        quantized storage dtype (uint8/uint16) when ``quantized``.
+      val_flat / col_flat: flattened segments, padded by
+        ``block_tail(max_w)`` trailing elements so the fixed-size staging
+        DMA never over-reads (``repro.kernels.ops.block_ell_spmm`` pads).
+      b: dense operand [num_nodes, feat]; feat % (pack * block_f) == 0
+        with ``pack = lane_pack(b.dtype)``.  f32, or the quantized storage
+        dtype (uint8/uint16) when ``quantized``.
       max_w: max width over the blocks in ``table`` — static row-DMA size.
       quantized / scale / x_min: fuse Eq. 2 (``b * scale + x_min``) into
         the B-row gather, so the hot loop moves 1-2 bytes per feature
@@ -237,32 +202,43 @@ def block_ell_spmm(table, live_w, val_flat, col_flat, b, *, block_rows: int,
     num_blocks = table.shape[0]
     rows = num_blocks * block_rows
     feat = b.shape[1]
-    assert feat % block_f == 0
+    pack = lane_pack(b.dtype, feat, block_f)
+    tile = pack * block_f
+    assert feat % tile == 0
+    bt = feature_tiles(b, block_f, pack)
 
-    grid = (num_blocks, feat // block_f)
-    kernel = functools.partial(_block_ell_spmm_kernel, block_f=block_f,
-                               max_w=max_w, block_rows=block_rows,
-                               quantized=quantized, scale=scale, x_min=x_min)
+    grid = (num_blocks, feat // tile)
+    kernel = functools.partial(
+        _block_ell_spmm_kernel, max_w=max_w, pack=pack, scale=scale,
+        x_min=x_min, bits=8 * b.dtype.itemsize if quantized else None)
+    stage = flat_window(stage_rows(row_group(block_rows), max_w) * max_w)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 2), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_rows, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec((None, 1, 2), lambda i, j: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, 1, block_rows), lambda i, j: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((block_rows, block_f), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((block_rows, tile), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, feat), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((max_w,), jnp.float32),      # row val landing zone
-            pltpu.VMEM((max_w,), jnp.int32),        # row col landing zone
-            pltpu.VMEM((2, 1, block_f), b.dtype),   # B-row landing zone
+            pltpu.SMEM((stage,), jnp.float32),      # group val landing zone
+            pltpu.SMEM((stage,), jnp.int32),        # group col landing zone
+            pltpu.VMEM((2, 1, 1, block_f), bt.dtype),  # B-row landing zone
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-    )(table, live_w.reshape(rows, 1).astype(jnp.int32), val_flat, col_flat, b)
+            dimension_semantics=("parallel", "parallel"),
+            # the double-buffered output block dominates: 4096 rows of a
+            # 512-feature quantized tile alone fill the 16 MiB default
+            vmem_limit_bytes=vmem_limit(2 * block_rows * tile * 4)),
+    )(table.reshape(num_blocks, 1, 2),
+      live_w.astype(jnp.int32).reshape(num_blocks, 1, block_rows),
+      val_flat, col_flat, bt)

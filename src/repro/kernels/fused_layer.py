@@ -7,12 +7,13 @@ dequantize pass at the feature boundary.  This kernel fuses the whole
 layer per row tile:
 
   * the sampled ``(val, col)`` tile and the per-row live widths stage in
-    VMEM via ``BlockSpec`` (same layout as ``ell_spmm.py``);
+    SMEM via ``BlockSpec`` (same layout as ``ell_spmm.py``);
   * each referenced B row is DMA'd from HBM with double buffering,
-    dequantized in the gather when the operand is int8
-    (``dequant_epilogue`` — the same Eq. 2 epilogue the unfused kernels
-    fuse), and accumulated into a VMEM row-tile aggregation buffer;
-  * the dense transform runs on the aggregation buffer *in VMEM*: one
+    dequantized in the gather when the operand is int8 (the shared core
+    of ``gather.py``, with the same Eq. 2 epilogue the unfused kernels
+    fuse), and accumulated into an on-chip ``[block_r, F]`` aggregation
+    tile;
+  * the dense transform runs on that tile on chip: one
     ``[block_r, F] @ [F, H]`` MXU matmul + bias + (optional) ReLU, and
     only the ``[block_r, H]`` layer output is ever written back to HBM.
 
@@ -24,8 +25,9 @@ layout; GE-SpMM's coalesced gather is the row-DMA analogue).
 The grid is 1-D over row tiles only: the dense transform contracts over
 the full feature dimension, so F is not tiled — the layer weights
 ``[F, H]`` must fit VMEM, which holds for GNN layer widths (the "small
-dense transform" regime this kernel targets; ``repro.kernels.ops``
-asserts the bound).
+dense transform" regime this kernel targets; :func:`vmem_bytes` counts the
+buffers and ``repro.kernels.ops`` refuses a layer over
+``VMEM_BUDGET``).
 """
 from __future__ import annotations
 
@@ -34,67 +36,60 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.dequant import dequant_epilogue
-from repro.kernels.pallas_compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.gather import (LANES, feature_tiles, for_row_groups,
+                                  gather_accumulate, lane_pack)
+
+# The kernel runs inside the default scoped VMEM of a v5e core.
+VMEM_BUDGET = 16 << 20
+
+
+def vmem_bytes(block_r: int, feat: int, hidden: int, pack: int = 1) -> int:
+    """VMEM the fused layer's buffers take at padded widths F (``feat``)
+    and H (``hidden``): one copy each of the grid-invariant ``[F, H]``
+    weight and ``[1, H]`` bias blocks, two of the ``[block_r, H]`` output
+    block, and the two-slot B-row landing zone of ``F / pack`` words.  At
+    F = H = 2560 Mosaic reports a 25.18 MiB scoped allocation for these
+    buffers on v5e, against 25.2 MiB counted here."""
+    return 4 * (feat * hidden + hidden + 2 * block_r * hidden
+                + 2 * (feat // pack))
 
 
 def _fused_layer_kernel(val_ref, col_ref, live_ref, w_ref, bias_ref, b_ref,
-                        out_ref, agg, bsc, sem, *, block_r: int, feat: int,
-                        quantized: bool, scale: float, x_min: float,
-                        relu: bool):
+                        out_ref, bsc, sem, *, pack: int, bits, scale: float,
+                        x_min: float, relu: bool):
     """grid = (row_tiles,).
 
-    val_ref:  f32[block_r, W]    VMEM  sampled edge weights
-    col_ref:  i32[block_r, W]    VMEM  sampled column indices
-    live_ref: i32[block_r, 1]    VMEM  live width per row
+    val_ref:  f32[block_r, W]    SMEM  sampled edge weights
+    col_ref:  i32[block_r, W]    SMEM  sampled column indices
+    live_ref: i32[1, block_r]    SMEM  live width per row
     w_ref:    f32[F, H]          VMEM  layer weights (padded)
     bias_ref: f32[1, H]          VMEM  layer bias (padded)
-    b_ref:    [num_nodes, F]     HBM   dense features (f32 / uint8)
+    b_ref:    [F / (128 * pack), num_nodes, 128] HBM  dense features, f32
+              or uint8 packed ``pack`` to a word (``gather.feature_tiles``)
     out_ref:  f32[block_r, H]    VMEM  layer output tile
-    agg:      VMEM[block_r, F]   aggregation buffer (never leaves VMEM)
-    bsc:      VMEM[2, 1, F]      double-buffered B-row landing zone
+    bsc:      VMEM[2, F / (128 * pack), 1, 128]  double-buffered B-row
+              landing zone
     sem:      DMA semaphores [2]
     """
+    def row_acc(base, i):
+        r = base + i
+        return gather_accumulate(
+            b_ref, bsc, sem, live_ref[0, r], lambda k: col_ref[r, k],
+            lambda k: val_ref[r, k], pack=pack, bits=bits, scale=scale,
+            x_min=x_min)
 
-    def b_row_copy(c, slot):
-        return pltpu.make_async_copy(
-            b_ref.at[pl.ds(c, 1), pl.ds(0, feat)], bsc.at[slot],
-            sem.at[slot])
+    def transform(base, agg):
+        # Dense transform epilogue on the on-chip aggregation tile: one
+        # MXU matmul per row group, f32-exact; only [group, H] reaches HBM.
+        h = jnp.dot(agg, w_ref[...], precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32) + bias_ref[...]
+        if relu:
+            h = jnp.maximum(h, 0.0)
+        out_ref[pl.ds(base, agg.shape[0]), :] = h
 
-    def row_body(r, _):
-        live_w = live_ref[r, 0]
-
-        @pl.when(live_w > 0)
-        def _():
-            b_row_copy(col_ref[r, 0], 0).start()
-
-        def k_body(k, acc):
-            slot = jax.lax.rem(k, 2)
-
-            @pl.when(k + 1 < live_w)
-            def _():
-                b_row_copy(col_ref[r, k + 1], jax.lax.rem(k + 1, 2)).start()
-
-            b_row_copy(col_ref[r, k], slot).wait()
-            row = bsc[slot, 0, :]
-            if quantized:
-                row = dequant_epilogue(row, scale, x_min)
-            return acc + val_ref[r, k] * row
-
-        acc = jax.lax.fori_loop(
-            0, live_w, k_body, jnp.zeros((feat,), jnp.float32))
-        pl.store(agg, (pl.ds(r, 1), slice(None)), acc[None, :])
-        return _
-
-    jax.lax.fori_loop(0, block_r, row_body, None)
-
-    # Dense transform epilogue on the VMEM-resident aggregation tile: one
-    # MXU matmul per row tile; only [block_r, H] reaches HBM.
-    h = jnp.dot(agg[...], w_ref[...],
-                preferred_element_type=jnp.float32) + bias_ref[0, :]
-    if relu:
-        h = jnp.maximum(h, 0.0)
-    out_ref[...] = h
+    for_row_groups(out_ref.shape[0], row_acc, transform)
 
 
 @functools.partial(
@@ -106,38 +101,43 @@ def fused_layer(ell_val, ell_col, live_w, b, w, bias, *, block_r: int = 8,
                 relu: bool = True, interpret: bool = True):
     """out[r, :] = act(sum_k ell_val[r, k] * B[ell_col[r, k], :] @ W + bias).
 
-    Inputs must be padded: rows % block_r == 0, F and H % 128 == 0, and
-    W's rows padded to match B's columns (``repro.kernels.ops`` pads).
+    Inputs must be padded: rows % block_r == 0, H % 128 == 0, F a multiple
+    of ``128 * lane_pack(b.dtype, F, 128)``, and W's rows padded to match
+    B's columns (``repro.kernels.ops`` pads).
     """
     rows, width = ell_val.shape
     feat = b.shape[1]
     hidden = w.shape[1]
     assert rows % block_r == 0 and w.shape[0] == feat
+    pack = lane_pack(b.dtype, feat, LANES)
+    bt = feature_tiles(b, LANES, pack)
 
-    grid = (rows // block_r,)
     kernel = functools.partial(
-        _fused_layer_kernel, block_r=block_r, feat=feat,
-        quantized=quantized, scale=scale, x_min=x_min, relu=relu)
+        _fused_layer_kernel, pack=pack, scale=scale, x_min=x_min, relu=relu,
+        bits=8 * b.dtype.itemsize if quantized else None)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(rows // block_r,),
         in_specs=[
-            pl.BlockSpec((block_r, width), lambda i: (i, 0)),
-            pl.BlockSpec((block_r, width), lambda i: (i, 0)),
-            pl.BlockSpec((block_r, 1), lambda i: (i, 0)),
+            pl.BlockSpec((block_r, width), lambda i: (i, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((block_r, width), lambda i: (i, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, 1, block_r), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((feat, hidden), lambda i: (0, 0)),
             pl.BlockSpec((1, hidden), lambda i: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((block_r, hidden), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, hidden), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((block_r, feat), jnp.float32),   # aggregation tile
-            pltpu.VMEM((2, 1, feat), b.dtype),          # B-row landing zone
+            pltpu.VMEM((2, bt.shape[0], 1, LANES), bt.dtype),  # B rows
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-    )(ell_val, ell_col, live_w.reshape(rows, 1).astype(jnp.int32), w,
-      bias.reshape(1, hidden), b)
+    )(ell_val, ell_col,
+      live_w.astype(jnp.int32).reshape(rows // block_r, 1, block_r), w,
+      bias.reshape(1, hidden), bt)

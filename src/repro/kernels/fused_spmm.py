@@ -12,11 +12,13 @@ Per row (Alg. 1 lines 3-14):
 
 then the SpMM stage (lines 16-19) accumulates over the staged slots.
 
-TPU adaptation notes (DESIGN.md §2): each sample is one contiguous run of N
-elements, so the staging uses **one DMA per sample** of the maximal static
-size and masks the tail — the paper's "coarser N = fewer index computations"
-becomes "coarser N = fewer DMA descriptors" on TPU, the same economy.  The
-B-row gather reuses the double-buffered DMA loop of ``ell_spmm``.
+TPU adaptation notes: each sample is one contiguous run of N elements, so
+the staging uses **one DMA per sample** of a static size (the aligned
+window over the run) and masks the tail — the paper's "coarser N = fewer
+index computations" becomes "coarser N = fewer DMA descriptors" on TPU,
+the same economy.  The sampled row lands in an SMEM scratch row (the
+analogue of ``sh_val``/``sh_col``) with scalar stores, and the B-row
+gather is the shared double-buffered core of ``gather.py``.
 """
 from __future__ import annotations
 
@@ -25,9 +27,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from repro.kernels.pallas_compat import pltpu
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.sampling import PRIME_NUM, _BANDS, _R_THRESHOLDS
+
+from .gather import (feature_tiles, flat_window, for_row_groups,
+                     gather_accumulate, stage_flat)
 
 
 def _strategy_scalar(row_nnz, sh_width: int):
@@ -47,96 +52,66 @@ def _strategy_scalar(row_nnz, sh_width: int):
     return W, N, cnt
 
 
+def sample_row(ci_ref, av_ref, stage_i, stage_f, sem, row_start, row_nnz,
+               sh_width: int, put):
+    """Alg. 1 lines 7-14 for one row: each of the ``cnt`` samples copies
+    its CSR run into the SMEM stages and hands its N elements to
+    ``put(slot, col, val)`` for slots ``i + j * cnt``.
+
+    Returns the row's live width: the slots ``[0, live)`` are all put.
+    """
+    W, N, cnt = _strategy_scalar(row_nnz, sh_width)
+    span = jnp.maximum(row_nnz - N + 1, 1)
+
+    def sample_body(i, carry):
+        start = (i * PRIME_NUM) % span
+        off = stage_flat(((ci_ref, stage_i), (av_ref, stage_f)), sem,
+                         row_start + start, sh_width)
+
+        def elem_body(j, c):
+            put(i + j * cnt, stage_i[off + j], stage_f[off + j])
+            return c
+
+        jax.lax.fori_loop(0, jnp.minimum(N, sh_width), elem_body, 0)
+        return carry
+
+    live = row_nnz > 0
+    jax.lax.fori_loop(0, jnp.where(live, cnt, 0), sample_body, 0)
+    return jnp.where(live, jnp.minimum(N * cnt, W), 0)
+
+
 def _fused_kernel(rs_ref, nnz_ref, ci_ref, av_ref, b_ref, out_ref,
                   sh_val, sh_col, stage_i, stage_f, bsc, sem, bsem,
-                  *, sh_width: int, block_f: int):
+                  *, sh_width: int):
     """grid = (row_tiles, feat_tiles).
 
-    rs_ref/nnz_ref: i32[block_r, 1] VMEM — CSR row starts / row nnz
-    ci_ref/av_ref:  HBM — full CSR col_ind / val arrays
-    b_ref:          HBM — dense features [nodes, F]
-    sh_val/sh_col:  VMEM scratch [block_r, sh_width] — the "shared memory"
-    stage_i/stage_f: VMEM scratch [sh_width] — CSR run landing zones
-    bsc:            VMEM scratch [2, 1, block_f] — B-row landing zone
+    rs_ref/nnz_ref: i32[1, block_r] SMEM — CSR row starts / row nnz
+    ci_ref/av_ref:  HBM — full CSR col_ind / val arrays (padded)
+    b_ref:          HBM — dense features [feat tiles, nodes, block_f]
+    sh_val/sh_col:  SMEM scratch [sh_width] — the "shared memory" of one row
+    stage_i/stage_f: SMEM scratch — aligned landing windows of a CSR run
+    bsc:            VMEM scratch [2, 1, 1, block_f] — B-row landing zone
     """
-    f_start = pl.program_id(1) * block_f
-    block_r = rs_ref.shape[0]
+    b_tile = b_ref.at[pl.ds(pl.program_id(1), 1)]
 
-    def run_copy(ref, stage, gstart):
-        # One DMA per sample: maximal static width sh_width, masked later.
-        return pltpu.make_async_copy(
-            ref.at[pl.ds(gstart, sh_width)], stage, sem.at[0])
+    def row_acc(base, i):
+        r = base + i
 
-    def row_body(r, _):
-        row_start = rs_ref[r, 0]
-        row_nnz = nnz_ref[r, 0]
-        W, N, cnt = _strategy_scalar(row_nnz, sh_width)
-        span = jnp.maximum(row_nnz - N + 1, 1)
+        def put(slot, c, v):
+            sh_col[slot] = c
+            sh_val[slot] = v
 
         # --- sampling stage: fill sh_val/sh_col (Alg. 1 lines 7-14) -------
-        def sample_body(i):
-            start = (i * PRIME_NUM) % span
-            cp_i = run_copy(ci_ref, stage_i, row_start + start)
-            cp_i.start()
-            cp_i.wait()
-            cp_f = run_copy(av_ref, stage_f, row_start + start)
-            cp_f.start()
-            cp_f.wait()
-            # scatter the N staged elements to slots i + j*cnt, j < N
-            def elem_body(j, _):
-                slot = i + j * cnt
-                pl.store(sh_col, (pl.ds(r, 1), pl.ds(slot, 1)),
-                         stage_i[j].reshape(1, 1))
-                pl.store(sh_val, (pl.ds(r, 1), pl.ds(slot, 1)),
-                         stage_f[j].reshape(1, 1))
-                return _
-
-            jax.lax.fori_loop(0, jnp.minimum(N, sh_width), elem_body, None)
-            return None
-
-        # zero-init (dead slots must not contribute to the accumulation)
-        pl.store(sh_val, (pl.ds(r, 1), slice(None)),
-                 jnp.zeros((1, sh_width), sh_val.dtype))
-        pl.store(sh_col, (pl.ds(r, 1), slice(None)),
-                 jnp.zeros((1, sh_width), jnp.int32))
-
-        @pl.when(row_nnz > 0)
-        def _():
-            def do_sample(i, _):
-                sample_body(i)
-                return _
-            jax.lax.fori_loop(0, cnt, do_sample, None)
-
+        live = sample_row(ci_ref, av_ref, stage_i, stage_f, sem,
+                          rs_ref[0, r], nnz_ref[0, r], sh_width, put)
         # --- SpMM stage over staged slots (Alg. 1 lines 16-19) ------------
-        live_w = jnp.where(row_nnz > 0, jnp.minimum(N * cnt, W), 0)
+        return gather_accumulate(b_tile, bsc, bsem, live,
+                                 lambda k: sh_col[k], lambda k: sh_val[k])
 
-        def b_copy(c, slot):
-            return pltpu.make_async_copy(
-                b_ref.at[pl.ds(c, 1), pl.ds(f_start, block_f)],
-                bsc.at[slot], bsem.at[slot])
+    def store(base, tile):
+        out_ref[pl.ds(base, tile.shape[0]), :] = tile
 
-        @pl.when(live_w > 0)
-        def _():
-            # jnp scalar, not a Python int: older interpret-mode pl.load
-            # requires indices with a .shape
-            b_copy(pl.load(sh_col, (r, jnp.int32(0))), 0).start()
-
-        def k_body(k, acc):
-            slot = jax.lax.rem(k, 2)
-
-            @pl.when(k + 1 < live_w)
-            def _():
-                b_copy(pl.load(sh_col, (r, k + 1)), jax.lax.rem(k + 1, 2)).start()
-
-            b_copy(pl.load(sh_col, (r, k)), slot).wait()
-            return acc + pl.load(sh_val, (r, k)) * bsc[slot, 0, :]
-
-        acc = jax.lax.fori_loop(0, live_w, k_body,
-                                jnp.zeros((block_f,), jnp.float32))
-        pl.store(out_ref, (pl.ds(r, 1), slice(None)), acc[None, :])
-        return _
-
-    jax.lax.fori_loop(0, block_r, row_body, None)
+    for_row_groups(out_ref.shape[0], row_acc, store)
 
 
 @functools.partial(
@@ -147,40 +122,43 @@ def fused_aes_spmm(row_start, row_nnz, col_ind, val, b, *, sh_width: int,
                    interpret: bool = True):
     """AES-SpMM with sampling fused into the kernel (paper Alg. 1).
 
-    ``col_ind``/``val`` must be padded by >= sh_width trailing elements so
-    the fixed-size sample DMA never reads out of bounds (ops.py pads).
+    ``col_ind``/``val`` must be padded by >= ``flat_window(sh_width)``
+    trailing elements so the fixed-size sample DMA never reads out of
+    bounds (ops.py pads).  ``b`` is f32.
     """
     rows = row_start.shape[0]
     feat = b.shape[1]
     assert rows % block_r == 0 and feat % block_f == 0
 
     grid = (rows // block_r, feat // block_f)
-    kernel = functools.partial(_fused_kernel, sh_width=sh_width,
-                               block_f=block_f)
+    kernel = functools.partial(_fused_kernel, sh_width=sh_width)
+    stage = flat_window(sh_width)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_r, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_r, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
+            pl.BlockSpec((None, 1, block_r), lambda i, j: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, 1, block_r), lambda i, j: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((block_r, block_f), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, feat), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((block_r, sh_width), jnp.float32),   # sh_val
-            pltpu.VMEM((block_r, sh_width), jnp.int32),     # sh_col
-            pltpu.VMEM((sh_width,), jnp.int32),             # CSR col run stage
-            pltpu.VMEM((sh_width,), jnp.float32),           # CSR val run stage
-            pltpu.VMEM((2, 1, block_f), b.dtype),           # B-row stage
+            pltpu.SMEM((sh_width,), jnp.float32),           # sh_val
+            pltpu.SMEM((sh_width,), jnp.int32),             # sh_col
+            pltpu.SMEM((stage,), jnp.int32),                # CSR col run stage
+            pltpu.SMEM((stage,), jnp.float32),              # CSR val run stage
+            pltpu.VMEM((2, 1, 1, block_f), b.dtype),           # B-row stage
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-    )(row_start.reshape(rows, 1).astype(jnp.int32),
-      row_nnz.reshape(rows, 1).astype(jnp.int32),
-      col_ind, val, b)
+    )(row_start.astype(jnp.int32).reshape(rows // block_r, 1, block_r),
+      row_nnz.astype(jnp.int32).reshape(rows // block_r, 1, block_r),
+      col_ind, val, feature_tiles(b, block_f, 1))

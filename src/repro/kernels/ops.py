@@ -19,15 +19,11 @@ from .aes_sample import aes_sample as _aes_sample_kernel
 from .dequant import dequantize as _dequant_kernel
 from .ell_spmm import block_ell_spmm as _block_ell_spmm_kernel
 from .ell_spmm import ell_spmm as _ell_spmm_kernel
+from .fused_layer import VMEM_BUDGET as _FUSED_LAYER_VMEM_BUDGET
 from .fused_layer import fused_layer as _fused_layer_kernel
+from .fused_layer import vmem_bytes as _fused_layer_vmem_bytes
 from .fused_spmm import fused_aes_spmm as _fused_kernel
-
-# The fused layer kernel holds its aggregation tile, the layer weights and
-# the double-buffered B rows in VMEM simultaneously; bound the padded
-# feature/hidden widths so a layer that cannot fit fails loudly instead of
-# spilling. ~2 MB of f32 at the defaults — comfortably inside one core's
-# VMEM alongside the [F, H] weights.
-_FUSED_LAYER_MAX_DIM = 2048
+from .gather import LANES, block_tail, flat_window, lane_pack
 
 
 def _interpret_default() -> bool:
@@ -75,7 +71,7 @@ def ell_spmm(ell: ELL, b, live_w=None, *, block_r: int = 8,
     val = _pad_to(ell.val, block_r, 0)
     col = _pad_to(ell.col, block_r, 0)
     lw = _pad_to(live_w, block_r, 0)
-    bp = _pad_to(b, block_f, 1)
+    bp = _pad_to(b, block_f * lane_pack(b.dtype, feat, block_f), 1)
     kw = {}
     if quantized_meta is not None:
         scale, x_min = quantized_meta
@@ -122,18 +118,18 @@ def block_ell_spmm(bell: BlockELL, b, *, block_f: int = 128,
     feat = b.shape[1]
     if buckets is None:
         buckets = partition_width_buckets(bell.widths)
-    # The fixed-size row DMA over-reads up to its bucket width (<= global
-    # max_width) past the last segment; the stitcher pre-pads the flat
-    # arrays for this (plans built by other means fall back to a per-call
-    # pad).
-    need = bell.total_slots + bell.max_width
+    # The fixed-size staging DMA over-reads up to ``block_tail`` of the
+    # global max_width past the last segment; the stitcher pre-pads the
+    # flat arrays for this (plans built by other means fall back to a
+    # per-call pad).
+    need = bell.total_slots + block_tail(bell.max_width)
     if bell.val.shape[0] >= need:
         val_flat, col_flat = bell.val, bell.col
     else:
         short = need - bell.val.shape[0]
         val_flat = jnp.pad(bell.val, (0, short))
         col_flat = jnp.pad(bell.col, (0, short))
-    bp = _pad_to(b, block_f, 1)
+    bp = _pad_to(b, block_f * lane_pack(b.dtype, feat, block_f), 1)
     kw = {}
     if quantized_meta is not None:
         scale, x_min = quantized_meta
@@ -176,8 +172,7 @@ def block_ell_spmm(bell: BlockELL, b, *, block_f: int = 128,
 
 
 def fused_layer_spmm(ell: ELL, b, w, bias, live_w=None, *, relu: bool = True,
-                     block_r: int = 8, block_f: int = 128,
-                     quantized_meta=None, interpret=None):
+                     block_r: int = 8, quantized_meta=None, interpret=None):
     """Pallas fused GNN layer: gather + (dequant) + SpMM + dense transform
     + activation in one launch — the aggregation intermediate never
     round-trips HBM.
@@ -190,7 +185,7 @@ def fused_layer_spmm(ell: ELL, b, w, bias, live_w=None, *, relu: bool = True,
       bias: layer bias f32[hidden].
       live_w: optional int32[rows] live-prefix lengths.
       relu: apply ReLU after the bias add (False for a logits layer).
-      block_r / block_f: row-tile size and the feat/hidden pad multiple.
+      block_r: row-tile size.
       quantized_meta: ``(scale, x_min)`` enables the fused-dequant gather.
       interpret: force Pallas interpret mode (default: interpret off-TPU).
 
@@ -212,17 +207,20 @@ def fused_layer_spmm(ell: ELL, b, w, bias, live_w=None, *, relu: bool = True,
     val = _pad_to(ell.val, block_r, 0)
     col = _pad_to(ell.col, block_r, 0)
     lw = _pad_to(live_w, block_r, 0)
-    # F and H both pad to the feature-tile multiple; padded B columns and
-    # padded W rows/columns are zero, so they contribute nothing to the
-    # matmul (a quantized B's padded columns dequantize to x_min, but the
-    # matching W rows are zero).
-    bp = _pad_to(b, block_f, 1)
-    wp = _pad_to(_pad_to(w, block_f, 0), block_f, 1)
-    biasp = _pad_to(bias.reshape(-1), block_f, 0)
-    if bp.shape[1] > _FUSED_LAYER_MAX_DIM or wp.shape[1] > _FUSED_LAYER_MAX_DIM:
+    # F and H both pad to whole lane tiles (F to tiles of packed words of
+    # a quantized B); padded B columns and padded W rows/columns are zero,
+    # so they contribute nothing to the matmul (a quantized B's padded
+    # columns dequantize to x_min, but the matching W rows are zero).
+    pack = lane_pack(b.dtype, feat, LANES)
+    bp = _pad_to(b, LANES * pack, 1)
+    wp = _pad_to(_pad_to(w, LANES * pack, 0), LANES, 1)
+    biasp = _pad_to(bias.reshape(-1), LANES, 0)
+    need = _fused_layer_vmem_bytes(block_r, bp.shape[1], wp.shape[1], pack)
+    if need > _FUSED_LAYER_VMEM_BUDGET:
         raise ValueError(
-            f"fused layer dims F={feat}, H={hidden} exceed the VMEM budget "
-            f"({_FUSED_LAYER_MAX_DIM} padded); use the unfused path")
+            f"fused layer dims F={feat}, H={hidden} need {need} bytes of "
+            f"VMEM, over the kernel's budget of {_FUSED_LAYER_VMEM_BUDGET}; "
+            "use the unfused path")
     kw = {}
     if quantized_meta is not None:
         scale, x_min = quantized_meta
@@ -237,9 +235,9 @@ def aes_sample(csr: CSR, sh_width: int, *, block_r: int = 8,
     """Pallas AES sampling pre-pass: CSR -> ELL(width=sh_width).
 
     Args:
-      csr: source matrix; its ``col_ind``/``val`` are padded by ``sh_width``
-        trailing elements so the kernel's fixed-size run DMA never
-        over-reads.
+      csr: source matrix; its ``col_ind``/``val`` are padded by
+        ``flat_window(sh_width)`` trailing elements so the kernel's
+        fixed-size run DMA never over-reads.
       sh_width: static ELL width (the paper's shared-memory W knob).
       block_r: rows per Pallas program (row count padded to a multiple).
       interpret: force Pallas interpret mode (default: interpret off-TPU).
@@ -252,8 +250,8 @@ def aes_sample(csr: CSR, sh_width: int, *, block_r: int = 8,
     rows = csr.num_rows
     row_start = _pad_to(csr.row_ptr[:-1], block_r, 0)
     row_nnz = _pad_to(csr.row_nnz(), block_r, 0)
-    ci = jnp.pad(csr.col_ind, (0, sh_width))
-    av = jnp.pad(csr.val, (0, sh_width))
+    ci = jnp.pad(csr.col_ind, (0, flat_window(sh_width)))
+    av = jnp.pad(csr.val, (0, flat_window(sh_width)))
     val, col = _aes_sample_kernel(row_start, row_nnz, ci, av,
                                   sh_width=sh_width, block_r=block_r,
                                   interpret=interpret)
@@ -280,8 +278,8 @@ def fused_aes_spmm(csr: CSR, b, sh_width: int, *, block_r: int = 8,
     feat = b.shape[1]
     row_start = _pad_to(csr.row_ptr[:-1], block_r, 0)
     row_nnz = _pad_to(csr.row_nnz(), block_r, 0)
-    ci = jnp.pad(csr.col_ind, (0, sh_width))
-    av = jnp.pad(csr.val, (0, sh_width))
+    ci = jnp.pad(csr.col_ind, (0, flat_window(sh_width)))
+    av = jnp.pad(csr.val, (0, flat_window(sh_width)))
     bp = _pad_to(b, block_f, 1)
     out = _fused_kernel(row_start, row_nnz, ci, av, bp, sh_width=sh_width,
                         block_r=block_r, block_f=block_f, interpret=interpret)

@@ -42,6 +42,7 @@ non-blocking dispatch surface the continuous-batching runtime
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Sequence
 
 import jax
@@ -116,6 +117,13 @@ class _SpmdBundle:
         gidx = np.zeros((num, self.gcap), np.int64)
         for s, sh in enumerate(shards):
             gidx[s, :len(sh.gather_index)] = sh.gather_index
+        # Every per-shard stack is committed to the mesh along its leading
+        # (shard) axis, so shard s's operands live on device s; only a
+        # request's own feature matrix is scattered per call.
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        sharded = NamedSharding(self.mesh, PartitionSpec(SHARD_AXIS))
+        put = functools.partial(jax.device_put, device=sharded)
         self._gidx = jnp.asarray(gidx)
 
         # Global bucket bounds: each bucket covers widths in (prev, bound].
@@ -145,7 +153,7 @@ class _SpmdBundle:
                     idx[s, j * br:(j + 1) * br] = np.where(
                         dest < self.rows[s], dest, self.rows_p)
             self.bucket_args.append(
-                (jnp.asarray(val), jnp.asarray(col), jnp.asarray(idx)))
+                (put(val), put(col), put(idx)))
 
         # Resident operand for x=None requests: the quantized stack when
         # every shard's plan is quantized (uint8 across the wire) AND
@@ -168,12 +176,13 @@ class _SpmdBundle:
                 q[s, :p.quantized.q.shape[0]] = np.asarray(p.quantized.q)
                 scale[s, 0] = float(p.quantized.scale)
                 xmin[s, 0] = float(p.quantized.x_min)
-            self._resident = jnp.asarray(q)
-            self._scale = jnp.asarray(scale)
-            self._xmin = jnp.asarray(xmin)
+            self._resident = put(q)
+            self._scale = put(scale)
+            self._xmin = put(xmin)
         else:
-            self._resident = jnp.asarray(features)[self._gidx]
+            self._resident = put(jnp.asarray(features)[self._gidx])
             self._scale = self._xmin = None
+        self._zeros = put(np.zeros((num, 1), np.float32))
         self._compiled: dict = {}
 
     def _fn(self, feat: int, quant: bool):
@@ -181,7 +190,6 @@ class _SpmdBundle:
         key = (feat, quant)
         if key in self._compiled:
             return self._compiled[key]
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.kernels import ref
 
@@ -203,9 +211,9 @@ class _SpmdBundle:
 
         in_specs = [spec(3), spec(2), spec(2)]
         in_specs += [spec(3), spec(3), spec(2)] * len(bucket_args)
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=self.mesh, in_specs=tuple(in_specs),
-            out_specs=spec(3), check_rep=False))
+            out_specs=spec(3), check_vma=False))
         self._compiled[key] = fn
         return fn
 
@@ -213,7 +221,7 @@ class _SpmdBundle:
         """One sharded pass: x=None serves the resident (possibly uint8)
         operand; a dense ``[num_nodes, F]`` matrix is gathered per shard
         and served on the float path."""
-        zeros = jnp.zeros((self.num_shards, 1), jnp.float32)
+        zeros = self._zeros
         if x is None:
             quant = self._quant
             stack = self._resident
@@ -221,7 +229,8 @@ class _SpmdBundle:
             xmin = self._xmin if quant else zeros
         else:
             quant = False
-            stack = jnp.asarray(x, jnp.float32)[self._gidx]
+            stack = jax.device_put(jnp.asarray(x, jnp.float32)[self._gidx],
+                                   zeros.sharding)
             scale = xmin = zeros
         flat = [a for args in self.bucket_args for a in args]
         out = self._fn(int(stack.shape[-1]), quant)(stack, scale, xmin, *flat)

@@ -133,9 +133,11 @@ def _splice_block_ell(bell: BlockELL, csr, new_configs: dict) -> BlockELL:
         lives.append(live)
         widths.append(w)
         strategies.append(s)
-    max_w = max(widths)
-    vals.append(np.zeros(max_w, old_val.dtype))
-    cols.append(np.zeros(max_w, np.int32))
+    from repro.kernels.gather import block_tail
+
+    tail = block_tail(max(widths))
+    vals.append(np.zeros(tail, old_val.dtype))
+    cols.append(np.zeros(tail, np.int32))
     return BlockELL(
         val=jnp.asarray(np.concatenate(vals)),
         col=jnp.asarray(np.concatenate(cols)),

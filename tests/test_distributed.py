@@ -13,17 +13,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding
+from jax.sharding import AbstractMesh, NamedSharding
 
 from repro.configs import ALL_ARCHS, SHAPES, get_config, smoke_config
-from repro.distributed.mesh_compat import abstract_mesh
 from repro.distributed.sharding import (batch_shardings, cache_shardings,
                                         opt_shardings, param_shardings)
 from repro.models import init_cache, init_params, input_specs, loss_fn
 from repro.optim import adamw_init
 
-ABSTRACT_MESH = abstract_mesh((16, 16), ("data", "model"))
-ABSTRACT_MESH_MP = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+ABSTRACT_MESH = AbstractMesh((16, 16), ("data", "model"))
+ABSTRACT_MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_divisible(tree, shardings, mesh):

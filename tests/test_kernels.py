@@ -111,3 +111,33 @@ def test_empty_graph(rng):
     b = jnp.asarray(rng.normal(size=(8, 16)).astype(np.float32))
     got = ops.ell_spmm(_ell(g, 4), b)
     np.testing.assert_array_equal(np.asarray(got), 0)
+
+
+@pytest.mark.parametrize("feat", [200, 600])
+def test_quantized_gather_packs_words(rng, feat):
+    """uint8 features wider than one lane tile ride 2 (feat 200) or 4
+    (feat 600) to an int32 word; every quantized kernel unpacks them to
+    the dequantize-then-aggregate result."""
+    from repro.core.sampling import sample_csr_to_block_ell
+
+    g = random_csr(rng, 40, 5.0)
+    x = rng.normal(size=(40, feat)).astype(np.float32)
+    qf = quantize(x, 8)
+    meta = (qf.scale, qf.x_min)
+    xd = dequantize(qf)
+    tol = dict(rtol=1e-4, atol=1e-4)
+    ell = _ell(g, 8)
+    np.testing.assert_allclose(
+        np.asarray(ops.ell_spmm(ell, qf.q, quantized_meta=meta)),
+        np.asarray(ref.ell_spmm_rowloop(ell.val, ell.col, xd)), **tol)
+    bell = sample_csr_to_block_ell(g, [("aes", 8), ("full", 0), ("afs", 4)],
+                                   16)
+    np.testing.assert_allclose(
+        np.asarray(ops.block_ell_spmm(bell, qf.q, quantized_meta=meta)),
+        np.asarray(ref.block_ell_spmm(bell, xd)), **tol)
+    w = jnp.asarray(rng.normal(size=(feat, 24)).astype(np.float32))
+    bias = jnp.zeros(24, jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(ops.fused_layer_spmm(ell, qf.q, w, bias,
+                                        quantized_meta=meta)),
+        np.asarray(ref.fused_layer(ell.val, ell.col, xd, w, bias)), **tol)
