@@ -18,11 +18,11 @@ ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
 DEFAULT_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-def enable_compile_cache(default_dir: str | os.PathLike = DEFAULT_DIR) -> str:
+def enable_compile_cache() -> str:
     """Turn the persistent cache on; returns the directory in use."""
     path = os.environ.get(ENV_DIR)
     if not path:
-        path = str(default_dir)
+        path = str(DEFAULT_DIR)
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     return path

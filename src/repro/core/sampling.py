@@ -212,37 +212,48 @@ def sample_block_segment(csr, row_nnz_host, b: int, strat: str, width: int,
       row_nnz_host: host int array of per-row nnz (hoisted by the caller).
       b: block index.
       strat: key of :data:`STRATEGIES` or ``"full"`` (pads to the block's
-        own max row nnz; the width argument is ignored).
+        own max row nnz, rounded up to a power of two so that blocks share
+        compiled samplers; the width argument is ignored).
       width: requested ELL width (floored to 1).
       block_rows: rows per block; a short last block is zero-padded.
 
     Returns ``(val, col, live_w, width, strategy)`` with ``val``/``col`` of
     shape ``[block_rows, width]`` and ``live_w`` int32[block_rows].
     """
-    from repro.core.graph import ell_live_widths
-
-    num_rows = csr.num_rows
     r0 = b * block_rows
-    r1 = min(r0 + block_rows, num_rows)
-    sub_ptr = csr.row_ptr[r0:r1 + 1]
-    blk_nnz = row_nnz_host[r0:r1]
+    r1 = min(r0 + block_rows, csr.num_rows)
     if strat == "full":
-        width = int(blk_nnz.max()) if len(blk_nnz) else 0
-        fn = sample_csr_to_ell_sfs           # first-W == all when W >= max nnz
-    else:
-        fn = STRATEGIES[strat]
+        # first-W == all when W >= max nnz; a power-of-two W keeps the
+        # number of distinct segment shapes (one compile each) to ~log2
+        width = 1 << (int(row_nnz_host[r0:r1].max(initial=1)) - 1) \
+            .bit_length()
     width = max(int(width), 1)
     if csr.nnz == 0 or r1 <= r0:
-        v = jnp.zeros((r1 - r0, width), csr.val.dtype)
-        c = jnp.zeros((r1 - r0, width), jnp.int32)
+        v = jnp.zeros((block_rows, width), csr.val.dtype)
+        c = jnp.zeros((block_rows, width), jnp.int32)
+        live = jnp.zeros((block_rows,), jnp.int32)
     else:
-        v, c = fn(sub_ptr, csr.col_ind, csr.val, width)
-    pad = block_rows - (r1 - r0)
-    if pad:
-        v = jnp.pad(v, ((0, pad), (0, 0)))
-        c = jnp.pad(c, ((0, pad), (0, 0)))
-    return v, c, ell_live_widths(v, c), width, (
-        "full" if strat == "full" else strat)
+        v, c, live = _sample_segment(csr.row_ptr, csr.col_ind, csr.val, r0,
+                                     strat=strat, width=width, rows=r1 - r0,
+                                     block_rows=block_rows)
+    return v, c, live, width, strat
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("strat", "width", "rows", "block_rows"))
+def _sample_segment(row_ptr, col_ind, val, r0, *, strat: str, width: int,
+                    rows: int, block_rows: int):
+    """One compiled program per segment shape: the rows ``[r0, r0 +
+    rows)`` sampled at ``width``, padded to ``block_rows``, with their
+    live widths."""
+    from repro.core.graph import ell_live_widths
+
+    fn = sample_csr_to_ell_sfs if strat == "full" else STRATEGIES[strat]
+    sub_ptr = jax.lax.dynamic_slice_in_dim(row_ptr, r0, rows + 1)
+    v, c = fn(sub_ptr, col_ind, val, width)
+    pad = ((0, block_rows - rows), (0, 0))
+    v, c = jnp.pad(v, pad), jnp.pad(c, pad)
+    return v, c, ell_live_widths(v, c)
 
 
 def sample_csr_to_block_ell(csr, configs, block_rows: int):
@@ -253,8 +264,8 @@ def sample_csr_to_block_ell(csr, configs, block_rows: int):
       configs: sequence of ``(strategy, width)`` pairs, one per row block
         (``ceil(num_rows / block_rows)`` entries).  ``strategy`` is a key of
         :data:`STRATEGIES` or ``"full"``; for ``"full"`` the width argument
-        is ignored and the block pads to its own max row nnz (exact, no
-        edge dropped).
+        is ignored and the block pads to its own max row nnz rounded up to
+        a power of two (exact, no edge dropped).
       block_rows: rows per block.  The last block is padded with empty rows.
 
     Returns:
@@ -274,13 +285,15 @@ def sample_csr_to_block_ell(csr, configs, block_rows: int):
             f"block_rows={block_rows}, got {len(configs)}")
 
     row_nnz_host = np.asarray(csr.row_ptr[1:]) - np.asarray(csr.row_ptr[:-1])
+    # Segments are assembled on the host: a device concatenate of one
+    # operand per block compiles anew for every block count and shape mix.
     vals, cols, lives, widths, strategies = [], [], [], [], []
     for b, (strat, width) in enumerate(configs):
         v, c, live, w, s = sample_block_segment(
             csr, row_nnz_host, b, strat, width, block_rows)
-        lives.append(live)
-        vals.append(v.reshape(-1))
-        cols.append(c.reshape(-1))
+        lives.append(np.asarray(live))
+        vals.append(np.asarray(v).reshape(-1))
+        cols.append(np.asarray(c).reshape(-1))
         widths.append(w)
         strategies.append(s)
 
@@ -290,11 +303,12 @@ def sample_csr_to_block_ell(csr, configs, block_rows: int):
     from repro.kernels.gather import block_tail
 
     tail = block_tail(max(widths))
-    vals.append(jnp.zeros(tail, csr.val.dtype))
-    cols.append(jnp.zeros(tail, jnp.int32))
+    vals.append(np.zeros(tail, vals[0].dtype))
+    cols.append(np.zeros(tail, np.int32))
     bell = BlockELL(
-        val=jnp.concatenate(vals), col=jnp.concatenate(cols),
-        live_w=jnp.concatenate(lives), widths=tuple(widths),
+        val=jnp.asarray(np.concatenate(vals)),
+        col=jnp.asarray(np.concatenate(cols)),
+        live_w=jnp.asarray(np.concatenate(lives)), widths=tuple(widths),
         strategies=tuple(strategies), block_rows=block_rows,
         num_rows=num_rows, num_cols=csr.num_cols)
     if obs.enabled():

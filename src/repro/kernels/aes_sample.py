@@ -19,7 +19,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .fused_spmm import sample_row
-from .gather import flat_window
+from .gather import edge_tile_smem_bytes, flat_window, smem_words
+
+
+def smem_bytes(block_r: int, sh_width: int) -> int:
+    """SMEM of :func:`aes_sample`: its double-buffered ``[block_r,
+    sh_width]`` output tiles and row blocks (the shape of an ELL kernel's
+    edge list), and the CSR run stages."""
+    return edge_tile_smem_bytes(block_r, sh_width) \
+        + 4 * (2 * smem_words((flat_window(sh_width),))
+               + 2 * smem_words((1, block_r)))
 
 
 def _sample_kernel(rs_ref, nnz_ref, ci_ref, av_ref, val_out, col_out,

@@ -15,10 +15,11 @@ This is the SpMM stage of Algorithm 1 (lines 16-19), re-thought for TPU:
     paper's ``for k <- 0 to W`` with the same dynamic bound
     ``W = min(row_nnz, sh_width)``.
 
-A quantized variant (``quantized=True``, available on both the fixed-width
-and the block-dispatched kernel) keeps B as uint8 in HBM and fuses Eq. 2
-dequantization into the gather — beyond-paper: it cuts the gather's HBM
-bytes 4x, and the gather is the memory-bound hot loop on TPU.  The blocked
+A quantized B (a ``TiledFeatures`` with ``bits`` set, on both the
+fixed-width and the block-dispatched kernel) stays packed in HBM and Eq. 2
+dequantization is fused into the gather — beyond-paper: from F > 128 it
+cuts the gather's HBM bytes up to 4x (a row DMA moves at least 128 words,
+so at F = 128 a uint8 row costs as much as a float one).  The blocked
 kernel is additionally launched once per *width bucket* by the ops wrapper,
 so narrow tail blocks stage their rows with a narrow static DMA instead of
 the global max width.
@@ -32,10 +33,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.gather import (FLAT_TILE, feature_tiles, flat_window,
+from repro.kernels.gather import (FLAT_TILE, TiledFeatures, flat_window,
                                   for_row_groups, gather_accumulate,
-                                  lane_pack, row_group, stage_flat,
+                                  row_group, smem_words, stage_flat,
                                   stage_rows, vmem_limit)
+
+
+def block_smem_bytes(block_rows: int, max_w: int) -> int:
+    """SMEM of :func:`block_ell_spmm` for one width bucket: the val/col
+    staging windows, and the double-buffered block-table and live-width
+    blocks."""
+    stage = flat_window(stage_rows(row_group(block_rows), max_w) * max_w)
+    return 4 * (2 * smem_words((stage,))
+                + 2 * (smem_words((1, 2)) + smem_words((1, block_rows))))
 
 
 def _ell_spmm_kernel(val_ref, col_ref, live_ref, b_ref, out_ref, bsc, sem,
@@ -46,7 +56,7 @@ def _ell_spmm_kernel(val_ref, col_ref, live_ref, b_ref, out_ref, bsc, sem,
     col_ref:  i32[block_r, W]   SMEM   sampled column indices
     live_ref: i32[1, block_r]   SMEM   live width per row (= min(nnz, W))
     b_ref:    [tiles, num_nodes, block_f] HBM  dense features, f32 or
-              quantized ones packed ``pack`` to a word (``feature_tiles``)
+              quantized ones packed ``pack`` to a word (``TiledFeatures``)
     out_ref:  f32[block_r, pack * block_f] VMEM
     bsc:      [2, 1, 1, block_f] VMEM   double-buffered B-row landing zone
     sem:      DMA semaphores [2]
@@ -67,30 +77,28 @@ def _ell_spmm_kernel(val_ref, col_ref, live_ref, b_ref, out_ref, bsc, sem,
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("block_r", "block_f", "quantized", "interpret",
-                     "scale", "x_min"))
-def ell_spmm(ell_val, ell_col, live_w, b, *, block_r: int = 8,
-             block_f: int = 128, quantized: bool = False,
-             scale=1.0, x_min=0.0, interpret: bool = True):
+    jax.jit, static_argnames=("block_r", "interpret", "scale", "x_min"))
+def ell_spmm(ell_val, ell_col, live_w, b: TiledFeatures, *,
+             block_r: int = 8, scale=1.0, x_min=0.0, interpret: bool = True):
     """C[r, :] = sum_k ell_val[r, k] * B[ell_col[r, k], :].
 
-    Inputs must be padded: rows % block_r == 0, feat % (pack * block_f)
-    == 0 with ``pack = lane_pack(b.dtype, feat, block_f)``
-    (``repro.kernels.ops`` pads).  A quantized B (``quantized=True``:
-    uint8/uint16) covers ``pack * block_f`` features per program.
+    Rows must be padded to a multiple of ``block_r`` (``repro.kernels.ops``
+    pads).  A quantized B (``b.bits`` set) is dequantized in the gather
+    with Eq. 2, ``q * scale + x_min``, and covers ``pack * width``
+    features per program.
+
+    Returns f32[rows, b.padded_features].
     """
     rows, width = ell_val.shape
-    feat = b.shape[1]
-    pack = lane_pack(b.dtype, feat, block_f)
+    assert rows % block_r == 0
+    block_f, pack = b.width, b.pack
     tile = pack * block_f
-    assert rows % block_r == 0 and feat % tile == 0
-    bt = feature_tiles(b, block_f, pack)
+    feat = b.padded_features
+    bt = b.tiles
 
     grid = (rows // block_r, feat // tile)
     kernel = functools.partial(
-        _ell_spmm_kernel, pack=pack, scale=scale, x_min=x_min,
-        bits=8 * b.dtype.itemsize if quantized else None)
+        _ell_spmm_kernel, pack=pack, scale=scale, x_min=x_min, bits=b.bits)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -173,11 +181,10 @@ def _block_ell_spmm_kernel(table_ref, live_ref, val_ref, col_ref, b_ref,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_rows", "block_f", "max_w", "quantized",
-                     "scale", "x_min", "interpret"))
-def block_ell_spmm(table, live_w, val_flat, col_flat, b, *, block_rows: int,
-                   max_w: int, block_f: int = 128, quantized: bool = False,
-                   scale=1.0, x_min=0.0, interpret: bool = True):
+    static_argnames=("block_rows", "max_w", "scale", "x_min", "interpret"))
+def block_ell_spmm(table, live_w, val_flat, col_flat, b: TiledFeatures, *,
+                   block_rows: int, max_w: int, scale=1.0, x_min=0.0,
+                   interpret: bool = True):
     """C[r, :] = sum_k seg_val[r, k] * B[seg_col[r, k], :] over mixed-width
     block segments.
 
@@ -189,28 +196,24 @@ def block_ell_spmm(table, live_w, val_flat, col_flat, b, *, block_rows: int,
       val_flat / col_flat: flattened segments, padded by
         ``block_tail(max_w)`` trailing elements so the fixed-size staging
         DMA never over-reads (``repro.kernels.ops.block_ell_spmm`` pads).
-      b: dense operand [num_nodes, feat]; feat % (pack * block_f) == 0
-        with ``pack = lane_pack(b.dtype)``.  f32, or the quantized storage
-        dtype (uint8/uint16) when ``quantized``.
+      b: the dense operand, f32 or quantized (``b.bits`` set).
       max_w: max width over the blocks in ``table`` — static row-DMA size.
-      quantized / scale / x_min: fuse Eq. 2 (``b * scale + x_min``) into
-        the B-row gather, so the hot loop moves 1-2 bytes per feature
-        instead of 4.
+      scale / x_min: Eq. 2 (``q * scale + x_min``), fused into the B-row
+        gather of a quantized B.
 
-    Returns f32[num_blocks * block_rows, feat].
+    Returns f32[num_blocks * block_rows, b.padded_features].
     """
     num_blocks = table.shape[0]
     rows = num_blocks * block_rows
-    feat = b.shape[1]
-    pack = lane_pack(b.dtype, feat, block_f)
+    block_f, pack = b.width, b.pack
     tile = pack * block_f
-    assert feat % tile == 0
-    bt = feature_tiles(b, block_f, pack)
+    feat = b.padded_features
+    bt = b.tiles
 
     grid = (num_blocks, feat // tile)
     kernel = functools.partial(
         _block_ell_spmm_kernel, max_w=max_w, pack=pack, scale=scale,
-        x_min=x_min, bits=8 * b.dtype.itemsize if quantized else None)
+        x_min=x_min, bits=b.bits)
     stage = flat_window(stage_rows(row_group(block_rows), max_w) * max_w)
     return pl.pallas_call(
         kernel,

@@ -38,8 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.gather import (LANES, feature_tiles, for_row_groups,
-                                  gather_accumulate, lane_pack)
+from repro.kernels.gather import (LANES, TiledFeatures, for_row_groups,
+                                  gather_accumulate)
 
 # The kernel runs inside the default scoped VMEM of a v5e core.
 VMEM_BUDGET = 16 << 20
@@ -67,7 +67,7 @@ def _fused_layer_kernel(val_ref, col_ref, live_ref, w_ref, bias_ref, b_ref,
     w_ref:    f32[F, H]          VMEM  layer weights (padded)
     bias_ref: f32[1, H]          VMEM  layer bias (padded)
     b_ref:    [F / (128 * pack), num_nodes, 128] HBM  dense features, f32
-              or uint8 packed ``pack`` to a word (``gather.feature_tiles``)
+              or uint8 packed ``pack`` to a word (``gather.TiledFeatures``)
     out_ref:  f32[block_r, H]    VMEM  layer output tile
     bsc:      VMEM[2, F / (128 * pack), 1, 128]  double-buffered B-row
               landing zone
@@ -94,27 +94,25 @@ def _fused_layer_kernel(val_ref, col_ref, live_ref, w_ref, bias_ref, b_ref,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_r", "quantized", "scale", "x_min", "relu",
-                     "interpret"))
-def fused_layer(ell_val, ell_col, live_w, b, w, bias, *, block_r: int = 8,
-                quantized: bool = False, scale=1.0, x_min=0.0,
-                relu: bool = True, interpret: bool = True):
+    static_argnames=("block_r", "scale", "x_min", "relu", "interpret"))
+def fused_layer(ell_val, ell_col, live_w, b: TiledFeatures, w, bias, *,
+                block_r: int = 8, scale=1.0, x_min=0.0, relu: bool = True,
+                interpret: bool = True):
     """out[r, :] = act(sum_k ell_val[r, k] * B[ell_col[r, k], :] @ W + bias).
 
-    Inputs must be padded: rows % block_r == 0, H % 128 == 0, F a multiple
-    of ``128 * lane_pack(b.dtype, F, 128)``, and W's rows padded to match
-    B's columns (``repro.kernels.ops`` pads).
+    Inputs must be padded: rows % block_r == 0, H % 128 == 0, and W's rows
+    to B's padded features (``repro.kernels.ops`` pads).  A quantized B
+    (``b.bits`` set) is dequantized in the gather, ``q * scale + x_min``.
     """
     rows, width = ell_val.shape
-    feat = b.shape[1]
     hidden = w.shape[1]
-    assert rows % block_r == 0 and w.shape[0] == feat
-    pack = lane_pack(b.dtype, feat, LANES)
-    bt = feature_tiles(b, LANES, pack)
+    feat = b.padded_features
+    assert rows % block_r == 0 and w.shape[0] == feat and b.width == LANES
+    bt = b.tiles
 
     kernel = functools.partial(
-        _fused_layer_kernel, pack=pack, scale=scale, x_min=x_min, relu=relu,
-        bits=8 * b.dtype.itemsize if quantized else None)
+        _fused_layer_kernel, pack=b.pack, scale=scale, x_min=x_min,
+        relu=relu, bits=b.bits)
     return pl.pallas_call(
         kernel,
         grid=(rows // block_r,),

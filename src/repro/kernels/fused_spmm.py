@@ -31,8 +31,17 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.sampling import PRIME_NUM, _BANDS, _R_THRESHOLDS
 
-from .gather import (feature_tiles, flat_window, for_row_groups,
-                     gather_accumulate, stage_flat)
+from .gather import (TiledFeatures, flat_window, for_row_groups,
+                     gather_accumulate, smem_words, stage_flat)
+
+
+def smem_bytes(block_r: int, sh_width: int) -> int:
+    """SMEM of :func:`fused_aes_spmm`: the sampled row (``sh_val``,
+    ``sh_col``), the CSR run stages, and the double-buffered row-start and
+    row-nnz blocks."""
+    return 4 * (2 * smem_words((sh_width,))
+                + 2 * smem_words((flat_window(sh_width),))
+                + 2 * 2 * smem_words((1, block_r)))
 
 
 def _strategy_scalar(row_nnz, sh_width: int):
@@ -115,20 +124,21 @@ def _fused_kernel(rs_ref, nnz_ref, ci_ref, av_ref, b_ref, out_ref,
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("sh_width", "block_r", "block_f", "interpret"))
-def fused_aes_spmm(row_start, row_nnz, col_ind, val, b, *, sh_width: int,
-                   block_r: int = 8, block_f: int = 128,
-                   interpret: bool = True):
+    jax.jit, static_argnames=("sh_width", "block_r", "interpret"))
+def fused_aes_spmm(row_start, row_nnz, col_ind, val, b: TiledFeatures, *,
+                   sh_width: int, block_r: int = 8, interpret: bool = True):
     """AES-SpMM with sampling fused into the kernel (paper Alg. 1).
 
     ``col_ind``/``val`` must be padded by >= ``flat_window(sh_width)``
     trailing elements so the fixed-size sample DMA never reads out of
-    bounds (ops.py pads).  ``b`` is f32.
+    bounds (ops.py pads).  ``b`` is a float operand.
+
+    Returns f32[rows, b.padded_features].
     """
     rows = row_start.shape[0]
-    feat = b.shape[1]
-    assert rows % block_r == 0 and feat % block_f == 0
+    block_f = b.width
+    feat = b.padded_features
+    assert rows % block_r == 0 and b.bits is None
 
     grid = (rows // block_r, feat // block_f)
     kernel = functools.partial(_fused_kernel, sh_width=sh_width)
@@ -152,7 +162,7 @@ def fused_aes_spmm(row_start, row_nnz, col_ind, val, b, *, sh_width: int,
             pltpu.SMEM((sh_width,), jnp.int32),             # sh_col
             pltpu.SMEM((stage,), jnp.int32),                # CSR col run stage
             pltpu.SMEM((stage,), jnp.float32),              # CSR val run stage
-            pltpu.VMEM((2, 1, 1, block_f), b.dtype),           # B-row stage
+            pltpu.VMEM((2, 1, 1, block_f), b.tiles.dtype),     # B-row stage
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
@@ -161,4 +171,4 @@ def fused_aes_spmm(row_start, row_nnz, col_ind, val, b, *, sh_width: int,
             dimension_semantics=("parallel", "parallel")),
     )(row_start.astype(jnp.int32).reshape(rows // block_r, 1, block_r),
       row_nnz.astype(jnp.int32).reshape(rows // block_r, 1, block_r),
-      col_ind, val, feature_tiles(b, block_f, 1))
+      col_ind, val, b.tiles)

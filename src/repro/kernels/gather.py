@@ -1,4 +1,5 @@
-"""The gather-accumulate core shared by every SpMM kernel in this package.
+"""The gather-accumulate core shared by every SpMM kernel in this package,
+and the one module that knows the layout of the dense operand B.
 
 Each sampled edge ``(val, col)`` of a row pulls one ``[1, width]`` row of
 the dense operand B out of HBM with its own DMA, double-buffered so the
@@ -6,10 +7,10 @@ copy of edge k+1 overlaps the FMA of edge k.  Three Mosaic rules shape it:
 
   * a DMA address and a loop bound are scalars, and scalars live in
     **SMEM** — every edge list a kernel reads sits in an SMEM block or an
-    SMEM scratch;
+    SMEM scratch, and SMEM is small (:data:`SMEM_BUDGET`);
   * a DMA out of a tiled HBM array may cut a single row only out of an
     array one lane tile (128 words) wide, so B reaches the kernels as a
-    stack of such arrays, ``[tiles, nodes, 128]`` (:func:`feature_tiles`),
+    stack of such arrays, ``[tiles, nodes, 128]`` (:class:`TiledFeatures`),
     and one strided DMA fetches a row from several of them; a 1-D array
     is read in windows aligned to its 1024-element tiling
     (:func:`stage_flat`);
@@ -20,7 +21,10 @@ copy of edge k+1 overlaps the FMA of edge k.  Three Mosaic rules shape it:
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
 import math
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -34,8 +38,37 @@ LANES = 128
 # HBM tiling of a 1-D 32-bit array: a DMA out of one starts and ends on a
 # multiple of this many elements.
 FLAT_TILE = 1024
-# SMEM words one staging window may take (SMEM holds 1 MiB on v5e).
+# SMEM a kernel's blocks and scratch may take: a v5e core's 1 MiB, less
+# what Mosaic keeps for itself (2.1-3.2 KiB in the v5e compiles of
+# tests/test_tpu_compile.py).
+SMEM_BUDGET = (1 << 20) - (4 << 10)
+# SMEM words one staging window may take.
 STAGE_WORDS = 1 << 16
+
+
+def smem_words(shape) -> int:
+    """32-bit words an SMEM array of ``shape`` occupies: a 1-D array pads
+    to whole 1024-word tiles, a 2-D one each row to whole 128-word lanes."""
+    if len(shape) == 1:
+        return pl.cdiv(shape[0], FLAT_TILE) * FLAT_TILE
+    *lead, last = shape
+    return math.prod(lead) * pl.cdiv(last, LANES) * LANES
+
+
+def edge_tile_smem_bytes(block_r: int, width: int) -> int:
+    """SMEM of a row tile's edge list as the ELL kernels stage it: the
+    ``[block_r, width]`` val and col blocks and the ``[1, block_r]`` live
+    widths, each double-buffered by the pipeline."""
+    return 4 * 2 * (2 * smem_words((block_r, width))
+                    + smem_words((1, block_r)))
+
+
+def check_smem(nbytes: int, what: str) -> None:
+    """Refuse a launch whose SMEM buffers exceed :data:`SMEM_BUDGET`."""
+    if nbytes > SMEM_BUDGET:
+        raise ValueError(
+            f"{what} needs {nbytes} bytes of SMEM, over the budget of "
+            f"{SMEM_BUDGET}; use a narrower ELL width or the jax backend")
 
 
 def lane_pack(dtype, feat: int, width: int) -> int:
@@ -52,20 +85,52 @@ def lane_pack(dtype, feat: int, width: int) -> int:
     return pack
 
 
-def feature_tiles(b, width: int, pack: int):
-    """``[nodes, F] -> [F / (pack * width), nodes, width]``: a stack of HBM
-    arrays one lane tile wide (``width`` is 128 on the chip).
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["tiles"],
+                   meta_fields=["num_features", "pack", "bits"])
+@dataclasses.dataclass(frozen=True)
+class TiledFeatures:
+    """The dense operand B as the kernels read it: ``[F_p / (pack *
+    width), nodes, width]``, a stack of HBM arrays one lane tile wide
+    (``width`` is 128 on the chip), F padded with zero features to whole
+    tiles.
 
-    A float operand keeps its dtype (a pure reshape when ``width == F``).
-    A quantized one (uint8/uint16) goes into int32 words, ``pack``
-    features each: Mosaic cannot cut one row out of the sub-word tiling of
-    a narrow integer array, and a row DMA moves at least 128 words.  Word
-    ``j`` of a tile holds the tile's features ``j, width + j, ...``, so
+    A float operand keeps its dtype (``bits`` None).  A quantized one
+    (uint8/uint16, ``bits`` wide) goes into int32 words, ``pack`` features
+    each: Mosaic cannot cut one row out of the sub-word tiling of a narrow
+    integer array, and a row DMA moves at least 128 words.  Word ``j`` of
+    a tile holds the tile's features ``j, width + j, ...``, so
     :func:`unpack_words` turns a row into ``[pack, width]`` lane-aligned
-    pieces with shifts and masks alone.
+    pieces with shifts and masks alone.  A row DMA moves ``4 * width``
+    bytes whatever ``pack`` is, so a uint8 operand moves fewer bytes than
+    a float one only from F > 128.
     """
+
+    tiles: jax.Array
+    num_features: int           # F before padding
+    pack: int
+    bits: int | None
+
+    @property
+    def width(self) -> int:
+        return self.tiles.shape[2]
+
+    @property
+    def padded_features(self) -> int:
+        """F_p: features the kernels compute, padding included."""
+        return self.tiles.shape[0] * self.pack * self.width
+
+
+@functools.partial(jax.jit, static_argnames=("width",))
+def tile_features(b, width: int = LANES) -> TiledFeatures:
+    """``[nodes, F] -> TiledFeatures``: one pass over B."""
     n, f = b.shape
-    x = b.reshape(n, f // (pack * width), pack, width)
+    pack = lane_pack(b.dtype, f, width)
+    fp = pl.cdiv(f, pack * width) * pack * width
+    if fp != f:
+        b = jnp.pad(b, ((0, 0), (0, fp - f)))
+    x = b.reshape(n, fp // (pack * width), pack, width)
+    bits = None
     if jnp.issubdtype(b.dtype, jnp.integer):
         bits = 8 * b.dtype.itemsize
         shift = (bits * jnp.arange(pack, dtype=jnp.uint32))[None, None, :,
@@ -75,12 +140,34 @@ def feature_tiles(b, width: int, pack: int):
         x = jax.lax.bitcast_convert_type(words, jnp.int32)
     else:
         x = x[:, :, 0, :]
-    return x.transpose(1, 0, 2)
+    return TiledFeatures(x.transpose(1, 0, 2), f, pack, bits)
+
+
+# Tiled layouts of the live jax.Arrays they were built from, so an
+# operand that is served many times (graph features, a plan's quantized
+# features) is re-laid out once, not on every SpMM call.
+_TILED: dict = {}
+
+
+def tiled(b, width: int = LANES) -> TiledFeatures:
+    """``b`` in the kernels' layout, built at most once per immutable
+    array (traced values and host arrays are laid out on every call)."""
+    if isinstance(b, TiledFeatures):
+        return b
+    if not isinstance(b, jax.Array) or isinstance(b, jax.core.Tracer):
+        return tile_features(jnp.asarray(b), width)
+    key = (id(b), width)
+    hit = _TILED.get(key)
+    if hit is not None and hit[0]() is b:
+        return hit[1]
+    t = tile_features(b, width)
+    _TILED[key] = (weakref.ref(b, lambda _: _TILED.pop(key, None)), t)
+    return t
 
 
 def unpack_words(row, pack: int, bits: int):
     """int32[1, width] words -> int32[pack, width]: piece ``k`` is the
-    ``k``-th ``bits``-wide field of every word (see :func:`feature_tiles`)."""
+    ``k``-th ``bits``-wide field of every word (see :class:`TiledFeatures`)."""
     shape = (pack, row.shape[-1])
     shift = jax.lax.broadcasted_iota(jnp.int32, shape, 0) * bits
     words = jnp.broadcast_to(row, shape)
@@ -145,7 +232,7 @@ def gather_accumulate(b_ref, bsc, sem, live, col_at, val_at, *,
 
     Args:
       b_ref: the dense operand's tiles this row reads, [tiles, nodes,
-        width] in HBM (see :func:`feature_tiles`): f32, or (``bits`` set)
+        width] in HBM (:attr:`TiledFeatures.tiles`): f32, or (``bits`` set)
         ``bits``-wide quantized features packed ``pack`` to an int32 word,
         dequantized with Eq. 2 (``q * scale + x_min``) as each row lands.
       bsc: VMEM scratch ``[2, tiles, 1, width]`` of b_ref's dtype, the

@@ -6,24 +6,30 @@ exposes a uniform signature over CSR/ELL inputs.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import CSR, ELL, BlockELL
 
-from . import ref
-from .aes_sample import aes_sample as _aes_sample_kernel
+from . import aes_sample as _aes_sample_mod
+from . import ell_spmm as _ell_mod
+from . import fused_layer as _fused_layer_mod
+from . import fused_spmm as _fused_mod
 from .dequant import dequantize as _dequant_kernel
-from .ell_spmm import block_ell_spmm as _block_ell_spmm_kernel
-from .ell_spmm import ell_spmm as _ell_spmm_kernel
-from .fused_layer import VMEM_BUDGET as _FUSED_LAYER_VMEM_BUDGET
-from .fused_layer import fused_layer as _fused_layer_kernel
-from .fused_layer import vmem_bytes as _fused_layer_vmem_bytes
-from .fused_spmm import fused_aes_spmm as _fused_kernel
-from .gather import LANES, block_tail, flat_window, lane_pack
+from .gather import (LANES, SMEM_BUDGET, block_tail, check_smem,
+                     edge_tile_smem_bytes, flat_window, tiled)
+
+
+def ell_fits_smem(width: int, *, sampled: bool = False,
+                  block_r: int = 8) -> bool:
+    """Whether an ELL operand ``width`` slots wide fits the SMEM of the
+    ELL kernels (``ell_spmm``, ``fused_layer_spmm``) at row tile
+    ``block_r`` — and, with ``sampled``, of the ``aes_sample`` kernel that
+    builds it.  A wider one runs on the jax backend only."""
+    need = _aes_sample_mod.smem_bytes(block_r, width) if sampled \
+        else edge_tile_smem_bytes(block_r, width)
+    return need <= SMEM_BUDGET
 
 
 def _interpret_default() -> bool:
@@ -40,6 +46,13 @@ def _pad_to(x, mult, axis, value=0):
     return jnp.pad(x, widths, constant_values=value)
 
 
+def _quant_kw(quantized_meta) -> dict:
+    if quantized_meta is None:
+        return {}
+    scale, x_min = quantized_meta
+    return dict(scale=float(scale), x_min=float(x_min))
+
+
 def ell_spmm(ell: ELL, b, live_w=None, *, block_r: int = 8,
              block_f: int = 128, quantized_meta=None, interpret=None):
     """Pallas ELL SpMM with padding.
@@ -48,11 +61,13 @@ def ell_spmm(ell: ELL, b, live_w=None, *, block_r: int = 8,
       ell: sampled operand; ``ell.val`` f32[rows, W], ``ell.col``
         int32[rows, W] (dead slots zeroed, live slots a contiguous prefix).
       b: dense operand [num_nodes, feat] — f32, or uint8 when
-        ``quantized_meta`` is given.
+        ``quantized_meta`` is given — or a ``gather.TiledFeatures``.
       live_w: optional int32[rows] live-prefix lengths; derived from the
         zero sentinel when omitted.
       block_r / block_f: Pallas tile sizes (rows and feat are padded up to
-        multiples of these; the padding is sliced off the result).
+        multiples of these; the padding is sliced off the result).  An
+        ELL wider than the kernel's SMEM holds at ``block_r`` is refused
+        (``ell_fits_smem``).
       quantized_meta: ``(scale, x_min)`` enables the fused-dequant gather
         (beyond-paper int8 path; B must then be uint8).
       interpret: force Pallas interpret mode (default: interpret unless
@@ -63,7 +78,9 @@ def ell_spmm(ell: ELL, b, live_w=None, *, block_r: int = 8,
     """
     interpret = _interpret_default() if interpret is None else interpret
     rows, width = ell.val.shape
-    feat = b.shape[1]
+    check_smem(edge_tile_smem_bytes(block_r, width),
+               f"ell_spmm at width {width}")
+    bt = tiled(b, block_f)
     if live_w is None:
         from repro.core.graph import ell_live_widths
 
@@ -71,14 +88,10 @@ def ell_spmm(ell: ELL, b, live_w=None, *, block_r: int = 8,
     val = _pad_to(ell.val, block_r, 0)
     col = _pad_to(ell.col, block_r, 0)
     lw = _pad_to(live_w, block_r, 0)
-    bp = _pad_to(b, block_f * lane_pack(b.dtype, feat, block_f), 1)
-    kw = {}
-    if quantized_meta is not None:
-        scale, x_min = quantized_meta
-        kw = dict(quantized=True, scale=float(scale), x_min=float(x_min))
-    out = _ell_spmm_kernel(val, col, lw, bp, block_r=block_r,
-                           block_f=block_f, interpret=interpret, **kw)
-    return out[:rows, :feat]
+    out = _ell_mod.ell_spmm(val, col, lw, bt, block_r=block_r,
+                            interpret=interpret,
+                            **_quant_kw(quantized_meta))
+    return out[:rows, :bt.num_features]
 
 
 def block_ell_spmm(bell: BlockELL, b, *, block_f: int = 128,
@@ -96,7 +109,8 @@ def block_ell_spmm(bell: BlockELL, b, *, block_f: int = 128,
     Args:
       bell: the stitched mixed-width operand (see ``core.graph.BlockELL``).
       b: dense operand [num_nodes, feat] — f32, or the quantized storage
-        dtype (uint8/uint16) when ``quantized_meta`` is given.
+        dtype (uint8/uint16) when ``quantized_meta`` is given — or a
+        ``gather.TiledFeatures``.
       block_f: feature-tile size (feat is padded up to a multiple).
       quantized_meta: ``(scale, x_min)`` enables the fused-dequant gather
         (Eq. 2 fused into the B-row fetch; B must then be quantized).
@@ -115,9 +129,13 @@ def block_ell_spmm(bell: BlockELL, b, *, block_f: int = 128,
     from repro.core.graph import partition_width_buckets
 
     interpret = _interpret_default() if interpret is None else interpret
-    feat = b.shape[1]
     if buckets is None:
         buckets = partition_width_buckets(bell.widths)
+    for bucket_w, _ in buckets:
+        check_smem(_ell_mod.block_smem_bytes(bell.block_rows, bucket_w),
+                   f"block_ell_spmm at width {bucket_w}")
+    bt = tiled(b, block_f)
+    feat = bt.num_features
     # The fixed-size staging DMA over-reads up to ``block_tail`` of the
     # global max_width past the last segment; the stitcher pre-pads the
     # flat arrays for this (plans built by other means fall back to a
@@ -129,11 +147,7 @@ def block_ell_spmm(bell: BlockELL, b, *, block_f: int = 128,
         short = need - bell.val.shape[0]
         val_flat = jnp.pad(bell.val, (0, short))
         col_flat = jnp.pad(bell.col, (0, short))
-    bp = _pad_to(b, block_f * lane_pack(b.dtype, feat, block_f), 1)
-    kw = {}
-    if quantized_meta is not None:
-        scale, x_min = quantized_meta
-        kw = dict(quantized=True, scale=float(scale), x_min=float(x_min))
+    kw = _quant_kw(quantized_meta)
 
     offs = bell.slot_offsets()
     br = bell.block_rows
@@ -144,10 +158,9 @@ def block_ell_spmm(bell: BlockELL, b, *, block_f: int = 128,
                             jnp.int32)
         lw = bell.live_w if ids == tuple(range(bell.num_blocks)) \
             else live2d[jnp.asarray(ids, jnp.int32)].reshape(-1)
-        results.append(_block_ell_spmm_kernel(
-            table, lw, val_flat, col_flat, bp,
-            block_rows=br, max_w=bucket_w,
-            block_f=block_f, interpret=interpret, **kw))
+        results.append(_ell_mod.block_ell_spmm(
+            table, lw, val_flat, col_flat, bt, block_rows=br,
+            max_w=bucket_w, interpret=interpret, **kw))
         order.extend(ids)
 
     # Reassembly costs one copy, not one full-output scatter per bucket:
@@ -166,7 +179,7 @@ def block_ell_spmm(bell: BlockELL, b, *, block_f: int = 128,
         return stacked[jnp.asarray(gather, jnp.int32)][:bell.num_rows, :feat]
     rows_idx = np.concatenate(
         [np.arange(i * br, (i + 1) * br) for i in order])
-    out = jnp.zeros((bell.padded_rows, bp.shape[1]), jnp.float32)
+    out = jnp.zeros((bell.padded_rows, bt.padded_features), jnp.float32)
     out = out.at[jnp.asarray(rows_idx, jnp.int32)].set(stacked)
     return out[:bell.num_rows, :feat]
 
@@ -180,7 +193,7 @@ def fused_layer_spmm(ell: ELL, b, w, bias, live_w=None, *, relu: bool = True,
     Args:
       ell: sampled operand (same contract as :func:`ell_spmm`).
       b: dense operand [num_nodes, feat] — f32, or uint8 when
-        ``quantized_meta`` is given.
+        ``quantized_meta`` is given — or a ``gather.TiledFeatures``.
       w: layer weights f32[feat, hidden].
       bias: layer bias f32[hidden].
       live_w: optional int32[rows] live-prefix lengths.
@@ -189,17 +202,36 @@ def fused_layer_spmm(ell: ELL, b, w, bias, live_w=None, *, relu: bool = True,
       quantized_meta: ``(scale, x_min)`` enables the fused-dequant gather.
       interpret: force Pallas interpret mode (default: interpret off-TPU).
 
+    A layer whose buffers exceed the kernel's VMEM budget, or an ELL wider
+    than its SMEM holds, is refused with a ``ValueError``.
+
     Returns:
       f32[rows, hidden] with
       ``out[r] = act(sum_k ell.val[r, k] * B[ell.col[r, k]] @ W + bias)``.
     """
     interpret = _interpret_default() if interpret is None else interpret
     rows, width = ell.val.shape
-    feat = b.shape[1]
     hidden = w.shape[1]
+    check_smem(edge_tile_smem_bytes(block_r, width),
+               f"fused_layer_spmm at width {width}")
+    bt = tiled(b, LANES)
+    feat = bt.num_features
     if w.shape[0] != feat:
         raise ValueError(
             f"weight rows {w.shape[0]} != operand features {feat}")
+    # B's features are padded to whole tiles of (packed) words and H to
+    # whole lane tiles; padded W rows/columns are zero, so the padding
+    # contributes nothing to the matmul (a quantized B's padded features
+    # dequantize to x_min, but the matching W rows are zero).
+    wp = _pad_to(_pad_to(w, bt.padded_features, 0), LANES, 1)
+    biasp = _pad_to(bias.reshape(-1), LANES, 0)
+    need = _fused_layer_mod.vmem_bytes(block_r, bt.padded_features,
+                                       wp.shape[1], bt.pack)
+    if need > _fused_layer_mod.VMEM_BUDGET:
+        raise ValueError(
+            f"fused layer dims F={feat}, H={hidden} need {need} bytes of "
+            f"VMEM, over the kernel's budget of "
+            f"{_fused_layer_mod.VMEM_BUDGET}; use the unfused path")
     if live_w is None:
         from repro.core.graph import ell_live_widths
 
@@ -207,26 +239,10 @@ def fused_layer_spmm(ell: ELL, b, w, bias, live_w=None, *, relu: bool = True,
     val = _pad_to(ell.val, block_r, 0)
     col = _pad_to(ell.col, block_r, 0)
     lw = _pad_to(live_w, block_r, 0)
-    # F and H both pad to whole lane tiles (F to tiles of packed words of
-    # a quantized B); padded B columns and padded W rows/columns are zero,
-    # so they contribute nothing to the matmul (a quantized B's padded
-    # columns dequantize to x_min, but the matching W rows are zero).
-    pack = lane_pack(b.dtype, feat, LANES)
-    bp = _pad_to(b, LANES * pack, 1)
-    wp = _pad_to(_pad_to(w, LANES * pack, 0), LANES, 1)
-    biasp = _pad_to(bias.reshape(-1), LANES, 0)
-    need = _fused_layer_vmem_bytes(block_r, bp.shape[1], wp.shape[1], pack)
-    if need > _FUSED_LAYER_VMEM_BUDGET:
-        raise ValueError(
-            f"fused layer dims F={feat}, H={hidden} need {need} bytes of "
-            f"VMEM, over the kernel's budget of {_FUSED_LAYER_VMEM_BUDGET}; "
-            "use the unfused path")
-    kw = {}
-    if quantized_meta is not None:
-        scale, x_min = quantized_meta
-        kw = dict(quantized=True, scale=float(scale), x_min=float(x_min))
-    out = _fused_layer_kernel(val, col, lw, bp, wp, biasp, block_r=block_r,
-                              relu=relu, interpret=interpret, **kw)
+    out = _fused_layer_mod.fused_layer(val, col, lw, bt, wp, biasp,
+                                       block_r=block_r, relu=relu,
+                                       interpret=interpret,
+                                       **_quant_kw(quantized_meta))
     return out[:rows, :hidden]
 
 
@@ -247,14 +263,16 @@ def aes_sample(csr: CSR, sh_width: int, *, block_r: int = 8,
       int32[num_rows, sh_width], dead slots zeroed.
     """
     interpret = _interpret_default() if interpret is None else interpret
+    check_smem(_aes_sample_mod.smem_bytes(block_r, sh_width),
+               f"aes_sample at width {sh_width}")
     rows = csr.num_rows
     row_start = _pad_to(csr.row_ptr[:-1], block_r, 0)
     row_nnz = _pad_to(csr.row_nnz(), block_r, 0)
     ci = jnp.pad(csr.col_ind, (0, flat_window(sh_width)))
     av = jnp.pad(csr.val, (0, flat_window(sh_width)))
-    val, col = _aes_sample_kernel(row_start, row_nnz, ci, av,
-                                  sh_width=sh_width, block_r=block_r,
-                                  interpret=interpret)
+    val, col = _aes_sample_mod.aes_sample(row_start, row_nnz, ci, av,
+                                          sh_width=sh_width, block_r=block_r,
+                                          interpret=interpret)
     return ELL(val[:rows], col[:rows], csr.num_cols)
 
 
@@ -274,16 +292,18 @@ def fused_aes_spmm(csr: CSR, b, sh_width: int, *, block_r: int = 8,
       materialized in HBM.
     """
     interpret = _interpret_default() if interpret is None else interpret
+    check_smem(_fused_mod.smem_bytes(block_r, sh_width),
+               f"fused_aes_spmm at width {sh_width}")
     rows = csr.num_rows
-    feat = b.shape[1]
+    bt = tiled(b, block_f)
     row_start = _pad_to(csr.row_ptr[:-1], block_r, 0)
     row_nnz = _pad_to(csr.row_nnz(), block_r, 0)
     ci = jnp.pad(csr.col_ind, (0, flat_window(sh_width)))
     av = jnp.pad(csr.val, (0, flat_window(sh_width)))
-    bp = _pad_to(b, block_f, 1)
-    out = _fused_kernel(row_start, row_nnz, ci, av, bp, sh_width=sh_width,
-                        block_r=block_r, block_f=block_f, interpret=interpret)
-    return out[:rows, :feat]
+    out = _fused_mod.fused_aes_spmm(row_start, row_nnz, ci, av, bt,
+                                    sh_width=sh_width, block_r=block_r,
+                                    interpret=interpret)
+    return out[:rows, :bt.num_features]
 
 
 def dequantize(q, scale, x_min, *, bits: int = 8, block_n: int = 256,

@@ -128,6 +128,10 @@ class _SpmdBundle:
 
         # Global bucket bounds: each bucket covers widths in (prev, bound].
         all_widths = [w for p in plans for w in p.bell.widths]
+        # segments are cut from host copies: one eager device slice per
+        # block would compile once per segment size
+        host = [(np.asarray(p.bell.val), np.asarray(p.bell.col),
+                 p.bell.slot_offsets()) for p in plans]
         bounds = [bw for bw, _ in
                   partition_width_buckets(all_widths, max_buckets)]
         self.bucket_args: list[tuple] = []
@@ -144,11 +148,12 @@ class _SpmdBundle:
             col = np.zeros((num, rcap, bw), np.int32)
             idx = np.full((num, rcap), self.rows_p, np.int32)  # dump row
             for s, p in enumerate(plans):
+                hv, hc, offs = host[s]
                 for j, bid in enumerate(sel[s]):
                     w = p.bell.widths[bid]
-                    v2, c2 = p.bell.block_segment(bid)
-                    val[s, j * br:(j + 1) * br, :w] = np.asarray(v2)
-                    col[s, j * br:(j + 1) * br, :w] = np.asarray(c2)
+                    seg = slice(offs[bid], offs[bid] + br * w)
+                    val[s, j * br:(j + 1) * br, :w] = hv[seg].reshape(br, w)
+                    col[s, j * br:(j + 1) * br, :w] = hc[seg].reshape(br, w)
                     dest = np.arange(bid * br, (bid + 1) * br)
                     idx[s, j * br:(j + 1) * br] = np.where(
                         dest < self.rows[s], dest, self.rows_p)

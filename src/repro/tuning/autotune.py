@@ -42,6 +42,7 @@ import numpy as np
 
 from repro import obs
 from repro.core.graph import CSR
+from repro.kernels import ops
 from repro.tuning import calibration, cost_model, features as features_mod, \
     measure
 from repro.tuning.cost_model import (CandidateConfig, DEFAULT_WIDTHS,
@@ -157,6 +158,14 @@ def tune(csr: CSR, features=None, *, budget: int = 6,
 
     candidates = list(grid) if grid is not None else default_grid(
         widths=widths, backends=backends or _default_backends(), quant=quant)
+    # A pallas candidate whose ELL rows (or aes_sample's tiles) overflow
+    # the kernels' SMEM cannot compile; leave it to the jax backend.
+    candidates = [c for c in candidates if c.backend != "pallas"
+                  or ops.ell_fits_smem(
+                      feats.max_row_nnz if c.strategy == "full"
+                      else c.sh_width, sampled=c.strategy == "aes")]
+    if not candidates:
+        raise ValueError("no candidate of the grid fits its backend")
     if synthetic_features:
         # Pre-quantizing a stand-in matrix would cache an operand no real
         # feature set can ever match — quantized plans need real features.

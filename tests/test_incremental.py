@@ -389,7 +389,9 @@ def test_property_patch_stream_matches_cold_tune(name, pairs, cut):
                for s in (g, sim)) or 1
     tk = dict(_TK, widths=(wmax, 2 * wmax), block_rows=16)
 
-    plan = tune_blocked(g, x, cache=None, **tk)
+    # refresh: the process-wide cache keys plans by graph, not by grid, so
+    # an earlier example's plan of ``g`` may hold another example's widths
+    plan = tune_blocked(g, x, cache=None, refresh=True, **tk)
     cur = g
     for chunk in (pairs[:cut], pairs[cut:]):
         adds, dels = _interpret_stream(cur, chunk)

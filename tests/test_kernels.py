@@ -141,3 +141,55 @@ def test_quantized_gather_packs_words(rng, feat):
         np.asarray(ops.fused_layer_spmm(ell, qf.q, w, bias,
                                         quantized_meta=meta)),
         np.asarray(ref.fused_layer(ell.val, ell.col, xd, w, bias)), **tol)
+
+
+def test_smem_bound_refuses_wide_ell(rng):
+    """An ELL wider than the kernels' SMEM holds is refused up front with
+    a ValueError (Mosaic would fail to compile it), and the tuner offers
+    no pallas candidate at that width."""
+    from repro.tuning.cost_model import CandidateConfig
+
+    g = random_csr(rng, 8, 2.0)
+    wide = next(w for w in range(8000, 9000) if not ops.ell_fits_smem(w))
+    ell = ELL(jnp.zeros((8, wide), jnp.float32),
+              jnp.zeros((8, wide), jnp.int32), g.num_cols)
+    b = jnp.ones((8, 16), jnp.float32)
+    with pytest.raises(ValueError, match="SMEM"):
+        ops.ell_spmm(ell, b)
+    with pytest.raises(ValueError, match="SMEM"):
+        ops.fused_layer_spmm(ell, b, jnp.ones((16, 4)), jnp.zeros(4))
+    with pytest.raises(ValueError, match="SMEM"):
+        ops.aes_sample(g, wide)
+    assert ops.ell_fits_smem(wide - 1)
+
+    from repro.tuning import PlanCache
+    from repro.tuning.autotune import tune
+
+    grid = [CandidateConfig("sfs", wide, "pallas"),
+            CandidateConfig("sfs", 4, "jax")]
+    plan = tune(g, np.asarray(b), grid=grid, budget=2, cache=PlanCache(),
+                warmup=0, iters=1)
+    assert plan.config.backend == "jax"
+
+
+def test_tiled_operand_is_built_once(rng):
+    """A live jax.Array is re-laid out into the kernels' tiles once and
+    reused by later calls; the layout is dropped with the array."""
+    from repro.kernels import gather
+
+    g = random_csr(rng, 16, 3.0)
+    x = jnp.asarray(rng.normal(size=(16, 40)).astype(np.float32))
+    qf = quantize(x, 8)
+    t1 = gather.tiled(qf.q)
+    assert gather.tiled(qf.q) is t1
+    assert (t1.num_features, t1.pack, t1.bits) == (40, 1, 8)
+    ell = _ell(g, 8)
+    got = ops.ell_spmm(ell, qf.q, quantized_meta=(qf.scale, qf.x_min))
+    np.testing.assert_allclose(
+        np.asarray(got),
+        np.asarray(ref.ell_spmm_rowloop(ell.val, ell.col, dequantize(qf))),
+        rtol=1e-4, atol=1e-4)
+    key = (id(qf.q), gather.LANES)
+    assert key in gather._TILED
+    del qf, t1
+    assert key not in gather._TILED

@@ -10,14 +10,17 @@ program holds the Mosaic kernel (``tpu_custom_call``).  Nothing runs.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import aes_sample, ell_spmm, fused_layer, fused_spmm
-from repro.kernels.gather import LANES, block_tail, flat_window, lane_pack
+from repro.kernels import aes_sample, ell_spmm, fused_layer, fused_spmm, ops
+from repro.kernels.gather import (LANES, SMEM_BUDGET, block_tail,
+                                  edge_tile_smem_bytes, flat_window,
+                                  tile_features)
 
 ROWS = 169_344               # ogbn-arxiv's 169,343 nodes, padded to 8 rows
 NNZ = 2_489_342              # its GCN adjacency at average degree 13.7
@@ -62,11 +65,11 @@ def _compile(fn, *args):
 
 
 def _operand(spec, feat: int, quant: bool):
-    """The dense operand at ``feat`` features padded as the ops wrappers
-    pad it: to whole lane tiles, of packed words when quantized."""
-    dtype = jnp.uint8 if quant else jnp.float32
-    tile = LANES * lane_pack(dtype, feat, LANES)
-    return spec((ROWS, -(-feat // tile) * tile), dtype)
+    """The dense operand at ``feat`` features in the kernels' layout
+    (``gather.TiledFeatures``), f32 or packed uint8."""
+    t = jax.eval_shape(tile_features, jax.ShapeDtypeStruct(
+        (ROWS, feat), jnp.uint8 if quant else jnp.float32))
+    return dataclasses.replace(t, tiles=spec(t.tiles.shape, t.tiles.dtype))
 
 
 def _ell(spec, width: int = W):
@@ -77,7 +80,7 @@ def _ell(spec, width: int = W):
 @pytest.mark.parametrize("quant", [False, True], ids=["f32", "uint8"])
 @pytest.mark.parametrize("feat", FEATS)
 def test_ell_spmm_compiles(spec, feat, quant):
-    kw = dict(quantized=True, scale=0.1, x_min=-1.0) if quant else {}
+    kw = dict(scale=0.1, x_min=-1.0) if quant else {}
     _compile(lambda v, c, lw, b: ell_spmm.ell_spmm(
         v, c, lw, b, interpret=False, **kw),
         *_ell(spec), _operand(spec, feat, quant))
@@ -88,7 +91,7 @@ def test_ell_spmm_compiles(spec, feat, quant):
 def test_block_ell_spmm_compiles(spec, feat, quant):
     blocks = -(-ROWS // BLOCK_ROWS)
     slots = BLOCK_ROWS * blocks * W + block_tail(W)
-    kw = dict(quantized=True, scale=0.1, x_min=-1.0) if quant else {}
+    kw = dict(scale=0.1, x_min=-1.0) if quant else {}
     _compile(lambda t, lw, v, c, b: ell_spmm.block_ell_spmm(
         t, lw, v, c, b, block_rows=BLOCK_ROWS, max_w=W, interpret=False,
         **kw),
@@ -101,10 +104,10 @@ def test_block_ell_spmm_compiles(spec, feat, quant):
 @pytest.mark.parametrize("feat", FEATS)
 def test_fused_layer_compiles(spec, feat, quant):
     b = _operand(spec, feat, quant)
-    kw = dict(quantized=True, scale=0.1, x_min=-1.0) if quant else {}
+    kw = dict(scale=0.1, x_min=-1.0) if quant else {}
     _compile(lambda v, c, lw, x, w, bias: fused_layer.fused_layer(
         v, c, lw, x, w, bias, interpret=False, **kw),
-        *_ell(spec), b, spec((b.shape[1], LANES), jnp.float32),
+        *_ell(spec), b, spec((b.padded_features, LANES), jnp.float32),
         spec((LANES,), jnp.float32))
 
 
@@ -117,7 +120,7 @@ def test_fused_layer_compiles_at_its_vmem_bound(spec):
         > fused_layer.VMEM_BUDGET
     _compile(lambda v, c, lw, x, w, bias: fused_layer.fused_layer(
         v, c, lw, x, w, bias, interpret=False),
-        *_ell(spec), spec((ROWS, dim), jnp.float32),
+        *_ell(spec), _operand(spec, dim, False),
         spec((dim, dim), jnp.float32), spec((dim,), jnp.float32))
 
 
@@ -137,3 +140,47 @@ def test_fused_aes_spmm_compiles(spec, feat):
         spec((ROWS,), jnp.int32), spec((ROWS,), jnp.int32),
         spec((pad,), jnp.int32), spec((pad,), jnp.float32),
         _operand(spec, feat, False))
+
+
+def _widest(fits) -> int:
+    """The largest width ``fits`` admits (``fits`` is monotone)."""
+    lo, hi = 1, 1 << 20
+    while hi - lo > 1:
+        mid = (lo + hi) // 2
+        lo, hi = (mid, hi) if fits(mid) else (lo, mid)
+    return lo
+
+
+@pytest.mark.parametrize("kernel", ["ell_spmm", "fused_layer", "aes_sample",
+                                    "fused_aes_spmm"])
+def test_compiles_at_its_smem_bound(spec, kernel):
+    """The widest ELL row the SMEM budget admits compiles for each kernel
+    whose SMEM grows with the width; one slot more is refused by the
+    budget, before Mosaic would refuse it."""
+    need = {"ell_spmm": lambda w: edge_tile_smem_bytes(8, w),
+            "fused_layer": lambda w: edge_tile_smem_bytes(8, w),
+            "aes_sample": lambda w: aes_sample.smem_bytes(8, w),
+            "fused_aes_spmm": lambda w: fused_spmm.smem_bytes(8, w)}[kernel]
+    w = _widest(lambda w: need(w) <= SMEM_BUDGET)
+    assert need(w + 1) > SMEM_BUDGET
+    if kernel in ("ell_spmm", "fused_layer"):
+        assert ops.ell_fits_smem(w) and not ops.ell_fits_smem(w + 1)
+    rows, pad = 4096, 4096 + flat_window(w)
+    ell = (spec((rows, w), jnp.float32), spec((rows, w), jnp.int32),
+           spec((rows,), jnp.int32))
+    b = _operand(spec, LANES, False)
+    csr = (spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+           spec((pad,), jnp.int32), spec((pad,), jnp.float32))
+    if kernel == "ell_spmm":
+        _compile(lambda v, c, lw, x: ell_spmm.ell_spmm(
+            v, c, lw, x, interpret=False), *ell, b)
+    elif kernel == "fused_layer":
+        _compile(lambda v, c, lw, x, wt, bias: fused_layer.fused_layer(
+            v, c, lw, x, wt, bias, interpret=False), *ell, b,
+            spec((LANES, LANES), jnp.float32), spec((LANES,), jnp.float32))
+    elif kernel == "aes_sample":
+        _compile(lambda rs, nz, ci, av: aes_sample.aes_sample(
+            rs, nz, ci, av, sh_width=w, interpret=False), *csr)
+    else:
+        _compile(lambda rs, nz, ci, av, x: fused_spmm.fused_aes_spmm(
+            rs, nz, ci, av, x, sh_width=w, interpret=False), *csr, b)
