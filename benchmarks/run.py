@@ -10,8 +10,8 @@ def main() -> None:
     from benchmarks import (accuracy_vs_w, autotune_gain, block_tuning_gain,
                             calibration_gain, fused_layer, incremental_update,
                             kernel_blocks, kernel_speedup, motivation,
-                            obs_overhead, quant_block_gain, quant_loading,
-                            reorder_gain, sampling_cdf, serving_throughput)
+                            quant_block_gain, quant_loading, reorder_gain,
+                            sampling_cdf, serving_throughput)
 
     print("name,us_per_call,derived")
     sampling_cdf.run()
@@ -36,9 +36,6 @@ def main() -> None:
     # degree-sorted vs natural row layout: padded-slot budget + bit parity
     # (-> BENCH_reorder.json, gate: parity + slots>=1.5x + auto picks)
     reorder_gain.run()
-    # tracing/metrics cost on the fused path
-    # (-> BENCH_obs.json, gate: disabled <1%, enabled <5%)
-    obs_overhead.run()
     try:
         from benchmarks import roofline
         roofline.report()

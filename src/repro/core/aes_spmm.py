@@ -33,39 +33,45 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro import obs
-from repro.core.graph import CSR, ELL, pad_csr_to_ell
+from repro.core.graph import CSR, ELL, ell_live_widths, pad_csr_to_ell
 from repro.core.quantization import QuantizedFeatures, dequantize
 from repro.core.sampling import STRATEGIES
+
+
+@jax.jit
+def _edge_counts(val, col, nnz):
+    """(kept, dropped) edges of one sampled operand, on the device:
+    live slots, and the rest of the ``nnz`` (at least 0, because AES may
+    duplicate hub edges)."""
+    kept = ell_live_widths(val, col).sum()
+    return kept, jnp.maximum(nnz - kept, 0)
 
 
 def sample(csr: CSR, sh_width: int, strategy: str = "aes",
            backend: str = "jax") -> ELL:
     """Sampling pre-pass producing the ELL operand."""
-    if strategy == "full":
-        ell = pad_csr_to_ell(csr)
-    elif backend == "pallas" and strategy == "aes":
-        from repro.kernels import ops
+    with obs.trace("sample", strategy=strategy, backend=backend):
+        if strategy == "full":
+            ell = pad_csr_to_ell(csr)
+        elif backend == "pallas" and strategy == "aes":
+            from repro.kernels import ops
 
-        ell = ops.aes_sample(csr, sh_width)
-    else:
-        fn = STRATEGIES[strategy]
-        val, col = fn(csr.row_ptr, csr.col_ind, csr.val, sh_width)
-        ell = ELL(val, col, csr.num_cols)
-    if obs.enabled():
-        # the paper's accuracy-vs-speed dial, as counters: how many edges
-        # the sampler kept vs. discarded on this call (one host pull of
-        # the per-row live widths; dropped is clamped at 0 because AES
-        # may duplicate hub edges)
-        from repro.core.graph import ell_live_widths
-
-        kept = int(np.asarray(ell_live_widths(ell.val, ell.col)).sum())
-        obs.count("sampler.calls")
-        obs.count(f"sampler.calls.{strategy}")
-        obs.count("sampler.edges_kept", kept)
-        obs.count("sampler.edges_dropped", max(int(csr.nnz) - kept, 0))
+            ell = ops.aes_sample(csr, sh_width)
+        else:
+            fn = STRATEGIES[strategy]
+            val, col = fn(csr.row_ptr, csr.col_ind, csr.val, sh_width)
+            ell = ELL(val, col, csr.num_cols)
+        if obs.enabled():
+            # the paper's accuracy-vs-speed dial, as counters: how many
+            # edges the sampler kept vs. discarded on this call, counted
+            # on the device and read only when the counters are read
+            kept, dropped = _edge_counts(ell.val, ell.col, csr.nnz)
+            obs.count("sampler.calls")
+            obs.count(f"sampler.calls.{strategy}")
+            obs.count_deferred("sampler.edges_kept", kept, csr.nnz)
+            obs.count_deferred("sampler.edges_dropped", dropped, csr.nnz)
     return ell
 
 

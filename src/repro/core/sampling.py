@@ -305,17 +305,19 @@ def sample_csr_to_block_ell(csr, configs, block_rows: int):
     tail = block_tail(max(widths))
     vals.append(np.zeros(tail, vals[0].dtype))
     cols.append(np.zeros(tail, np.int32))
+    live_w = np.concatenate(lives)
     bell = BlockELL(
         val=jnp.asarray(np.concatenate(vals)),
         col=jnp.asarray(np.concatenate(cols)),
-        live_w=jnp.asarray(np.concatenate(lives)), widths=tuple(widths),
+        live_w=jnp.asarray(live_w), widths=tuple(widths),
         strategies=tuple(strategies), block_rows=block_rows,
         num_rows=num_rows, num_cols=csr.num_cols)
     if obs.enabled():
         # blocked-path twin of the sample() quality counters: edges the
         # stitched mixed-width operand kept vs. discarded, plus the slot
-        # count the per-block widths allocated (tightness vs. nnz)
-        kept = int(bell.live_edges())
+        # count the per-block widths allocated (tightness vs. nnz); the
+        # live widths are the host's own
+        kept = int(live_w[:num_rows].sum())
         obs.count("sampler.block_calls")
         obs.count("sampler.edges_kept", kept)
         obs.count("sampler.edges_dropped", max(int(csr.nnz) - kept, 0))

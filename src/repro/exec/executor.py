@@ -61,17 +61,32 @@ def _guarded_requant(quantized, features, site: str):
     often a hidden-layer activation could ride the stored quantization
     range vs. fell back to the float path (or, for in-range operands whose
     distribution shrank past the drift threshold, got a freshly derived
-    range — see ``quantization.requantize_within_range``)."""
-    requanted = requantize_within_range(quantized, features)
-    if obs.enabled():
-        obs.count("quant.requant_in_range" if requanted is not None
-                  else "quant.requant_drift_fallback")
-        if requanted is not None and (
-                float(requanted.x_min) != float(quantized.x_min)
-                or float(requanted.x_max) != float(quantized.x_max)):
-            obs.count("quant.requant_range_refreshed")
-        obs.count(f"quant.requant_{site}")
-    return requanted
+    range — see ``quantization.requantize_within_range``).
+
+    Returns ``(requanted, meta)``: the re-encoded operand (None on drift)
+    and its kernel constants ``(scale, x_min)`` as host floats.  Every
+    host read the guarded path makes happens here, in the guard's span."""
+    with obs.trace("quant.requant_guard", site=site):
+        requanted = requantize_within_range(quantized, features)
+        if requanted is None:
+            meta = None
+        else:
+            meta = (float(requanted.scale), float(requanted.x_min))
+        if obs.enabled():
+            obs.count("quant.requant_in_range" if requanted is not None
+                      else "quant.requant_drift_fallback")
+            # a refreshed range is a new pair of arrays, not the stored one
+            if requanted is not None and requanted.x_min is not \
+                    quantized.x_min:
+                obs.count("quant.requant_range_refreshed")
+            obs.count(f"quant.requant_{site}")
+    return requanted, meta
+
+
+def _meta(quantized: QuantizedFeatures, meta):
+    """The dequant constants the kernels take: the guard's, else the
+    operand's own."""
+    return meta if meta is not None else (quantized.scale, quantized.x_min)
 
 
 class PlanExecutor:
@@ -112,8 +127,10 @@ class PlanExecutor:
 
         if isinstance(features, QuantizedFeatures):
             features = dequantize(features)
+        meta = None
         if quantized is not None and requant_guard:
-            quantized = _guarded_requant(quantized, features, "run_ell")
+            quantized, meta = _guarded_requant(quantized, features,
+                                               "run_ell")
         with obs.trace("exec.run_ell", backend=backend,
                        dtype=_dtype_tag(quantized)):
             if obs.enabled():
@@ -123,7 +140,7 @@ class PlanExecutor:
                 if quantized is not None:
                     return ops.ell_spmm(
                         ell, quantized.q,
-                        quantized_meta=(quantized.scale, quantized.x_min),
+                        quantized_meta=_meta(quantized, meta),
                         interpret=self.interpret)
                 return ops.ell_spmm(ell, features, interpret=self.interpret)
             x = dequantize(quantized) if quantized is not None else features
@@ -252,9 +269,10 @@ class PlanExecutor:
 
         if isinstance(features, QuantizedFeatures):
             features = dequantize(features)
+        meta = None
         if quantized is not None and requant_guard:
-            quantized = _guarded_requant(quantized, features,
-                                         "run_fused_layer")
+            quantized, meta = _guarded_requant(quantized, features,
+                                               "run_fused_layer")
         with obs.trace("exec.run_fused_layer", backend=backend,
                        dtype=_dtype_tag(quantized)):
             if obs.enabled():
@@ -264,7 +282,7 @@ class PlanExecutor:
                 if quantized is not None:
                     out = ops.fused_layer_spmm(
                         ell, quantized.q, w, bias, relu=relu,
-                        quantized_meta=(quantized.scale, quantized.x_min),
+                        quantized_meta=_meta(quantized, meta),
                         interpret=self.interpret)
                 else:
                     out = ops.fused_layer_spmm(ell, features, w, bias,

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.graph import CSR
 from repro.kernels import ref
 
@@ -71,8 +72,12 @@ def init_gcn(rng: np.random.Generator, feat: int, hidden: int,
 def GCN(params: GCNParams, adj: CSR, x: jax.Array,
         agg: AggFn = exact_agg) -> jax.Array:
     """2-layer GCN: softmax(A' relu(A' X W1) W2) with A' pre-normalized."""
-    h = jax.nn.relu(agg(adj, x) @ params.w1 + params.b1)
-    return agg(adj, h) @ params.w2 + params.b2
+    a = agg(adj, x)
+    with obs.trace("gnn.dense", layer=1):
+        h = jax.nn.relu(a @ params.w1 + params.b1)
+    a = agg(adj, h)
+    with obs.trace("gnn.dense", layer=2):
+        return a @ params.w2 + params.b2
 
 
 class SAGEParams(NamedTuple):
@@ -95,9 +100,13 @@ def init_sage(rng: np.random.Generator, feat: int, hidden: int,
 def GraphSAGE(params: SAGEParams, adj: CSR, x: jax.Array,
               agg: AggFn = exact_agg) -> jax.Array:
     """2-layer GraphSAGE-mean: h' = relu(W_self h + W_neigh mean_agg(h))."""
-    h = jax.nn.relu(x @ params.w_self1 + agg(adj, x) @ params.w_neigh1
-                    + params.b1)
-    return (h @ params.w_self2 + agg(adj, h) @ params.w_neigh2 + params.b2)
+    a = agg(adj, x)
+    with obs.trace("gnn.dense", layer=1):
+        h = jax.nn.relu(x @ params.w_self1 + a @ params.w_neigh1
+                        + params.b1)
+    a = agg(adj, h)
+    with obs.trace("gnn.dense", layer=2):
+        return h @ params.w_self2 + a @ params.w_neigh2 + params.b2
 
 
 MODELS = {

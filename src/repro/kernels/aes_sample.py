@@ -78,6 +78,7 @@ def aes_sample(row_start, row_nnz, col_ind, val, *, sh_width: int,
     stage = flat_window(sh_width)
     return pl.pallas_call(
         kernel,
+        name="aes_sample",
         grid=(rows // block_r,),
         in_specs=[
             pl.BlockSpec((None, 1, block_r), lambda i: (i, 0, 0),

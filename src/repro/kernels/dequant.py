@@ -45,6 +45,7 @@ def dequantize(q, *, scale: float, x_min: float, bits: int = 8,
     grid = (n // block_n, f // block_f)
     return pl.pallas_call(
         functools.partial(_dequant_kernel, scale=scale, x_min=x_min),
+        name="dequantize",
         grid=grid,
         in_specs=[pl.BlockSpec((block_n, block_f), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((block_n, block_f), lambda i, j: (i, j)),

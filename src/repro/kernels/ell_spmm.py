@@ -101,6 +101,7 @@ def ell_spmm(ell_val, ell_col, live_w, b: TiledFeatures, *,
         _ell_spmm_kernel, pack=pack, scale=scale, x_min=x_min, bits=b.bits)
     return pl.pallas_call(
         kernel,
+        name="ell_spmm",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_r, width), lambda i, j: (i, 0),
@@ -217,6 +218,7 @@ def block_ell_spmm(table, live_w, val_flat, col_flat, b: TiledFeatures, *,
     stage = flat_window(stage_rows(row_group(block_rows), max_w) * max_w)
     return pl.pallas_call(
         kernel,
+        name="block_ell_spmm",
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, 1, 2), lambda i, j: (i, 0, 0),

@@ -115,6 +115,7 @@ def fused_layer(ell_val, ell_col, live_w, b: TiledFeatures, w, bias, *,
         relu=relu, bits=b.bits)
     return pl.pallas_call(
         kernel,
+        name="fused_layer",
         grid=(rows // block_r,),
         in_specs=[
             pl.BlockSpec((block_r, width), lambda i: (i, 0),

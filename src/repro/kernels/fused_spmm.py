@@ -145,6 +145,7 @@ def fused_aes_spmm(row_start, row_nnz, col_ind, val, b: TiledFeatures, *,
     stage = flat_window(sh_width)
     return pl.pallas_call(
         kernel,
+        name="fused_aes_spmm",
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, 1, block_r), lambda i, j: (i, 0, 0),

@@ -39,7 +39,8 @@ from repro.obs.trace import (NOOP_SPAN, Span, Tracer, configure,
 
 __all__ = [
     "LatencyHistogram", "MetricsRegistry", "Span", "Tracer",
-    "build_trees", "configure", "count", "current_context", "decision",
+    "build_trees", "configure", "count", "count_deferred",
+    "current_context", "decision",
     "default_registry", "default_tracer", "enabled", "gauge",
     "load_trace_dir", "load_trace_file", "observe_us", "record_span",
     "render_summary", "request_context", "reset", "set_enabled",
@@ -52,6 +53,13 @@ def count(name: str, n: int = 1) -> None:
     """Increment a counter — no-op (one branch) when disabled."""
     if _trace_mod._enabled:
         default_registry().count(name, n)
+
+
+def count_deferred(name: str, value, bound: int) -> None:
+    """Add a device integer of at most ``bound`` to a counter without
+    reading it (read with the counters) — no-op when disabled."""
+    if _trace_mod._enabled:
+        default_registry().count_deferred(name, value, bound)
 
 
 def gauge(name: str, value: float) -> None:

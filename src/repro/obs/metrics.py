@@ -7,6 +7,11 @@ ints, gauges are last-write-wins floats, histograms are the log-spaced
 lives here now (``serving.telemetry`` re-exports it for compatibility)
 and carries its own lock so standalone concurrent ``record()`` is safe.
 
+A counter may also take device integers that are not read when they are
+added (:meth:`MetricsRegistry.count_deferred`): a count the device
+computes costs the hot path no host sync, and is read when the counters
+are.
+
 Naming convention: dotted lowercase, subsystem first —
 ``sampler.edges_dropped``, ``plan_cache.hit_memory``,
 ``executor.run_ell.pallas.quant``.  See docs/observability.md for the
@@ -124,18 +129,72 @@ class LatencyHistogram:
             self.max_us = 0.0
 
 
+#: Largest value a deferred counter's device sum may reach (int32).
+DEVICE_SUM_MAX = 2**31 - 1
+
+
+class _Deferred:
+    """The unread device integers of one counter: a running device sum
+    with room for ``room`` more, and the sum sealed before it."""
+
+    __slots__ = ("total", "room", "sealed")
+
+    def __init__(self):
+        self.total = self.sealed = None
+        self.room = DEVICE_SUM_MAX
+
+
 class MetricsRegistry:
     """Thread-safe flat namespace of counters / gauges / histograms."""
 
     def __init__(self):
-        self._mu = threading.Lock()
+        # reentrant: a deferred count's device add may trace, and JAX's
+        # trace listener counts into this registry on the same thread
+        self._mu = threading.RLock()
         self._counters: Dict[str, int] = {}
+        self._deferred: Dict[str, _Deferred] = {}
         self._gauges: Dict[str, float] = {}
         self._hists: Dict[str, LatencyHistogram] = {}
 
     def count(self, name: str, n: int = 1) -> None:
         with self._mu:
             self._counters[name] = self._counters.get(name, 0) + int(n)
+
+    def count_deferred(self, name: str, value, bound: int) -> None:
+        """Add ``value``, a device integer scalar of at most ``bound``, to
+        counter ``name`` without reading it.
+
+        Values fold into one running device sum per counter (``+`` on the
+        device, no sync).  Before the sum could pass
+        :data:`DEVICE_SUM_MAX` it is sealed, its copy to the host begun,
+        and a fresh sum started; a sealed sum is read at the next seal,
+        long after the device wrote it.  Every unread value is read by
+        the next :meth:`counter_value`, :meth:`counters` or
+        :meth:`snapshot`."""
+        with self._mu:
+            d = self._deferred.get(name)
+            if d is None:
+                d = self._deferred[name] = _Deferred()
+            if d.total is not None and bound > d.room:
+                if d.sealed is not None:
+                    self._add_locked(name, d.sealed)
+                d.sealed, d.total, d.room = d.total, None, DEVICE_SUM_MAX
+                start_copy = getattr(d.sealed, "copy_to_host_async", None)
+                if start_copy is not None:
+                    start_copy()
+            d.total = value if d.total is None else d.total + value
+            d.room -= bound
+
+    def _add_locked(self, name: str, value) -> None:
+        self._counters[name] = self._counters.get(name, 0) + int(value)
+
+    def _resolve_locked(self) -> None:
+        """Read every deferred value into its counter."""
+        for name, d in self._deferred.items():
+            for value in (d.sealed, d.total):
+                if value is not None:
+                    self._add_locked(name, value)
+        self._deferred.clear()
 
     def gauge(self, name: str, value: float) -> None:
         with self._mu:
@@ -154,6 +213,7 @@ class MetricsRegistry:
 
     def counter_value(self, name: str) -> int:
         with self._mu:
+            self._resolve_locked()
             return self._counters.get(name, 0)
 
     def gauge_value(self, name: str, default: float = 0.0) -> float:
@@ -162,12 +222,14 @@ class MetricsRegistry:
 
     def counters(self, prefix: str = "") -> Dict[str, int]:
         with self._mu:
+            self._resolve_locked()
             return {k: v for k, v in sorted(self._counters.items())
                     if k.startswith(prefix)}
 
     def snapshot(self) -> dict:
         """JSON-able view of every metric."""
         with self._mu:
+            self._resolve_locked()
             counters = dict(sorted(self._counters.items()))
             gauges = dict(sorted(self._gauges.items()))
             hists = {k: h for k, h in sorted(self._hists.items())}
@@ -185,11 +247,13 @@ class MetricsRegistry:
         with self._mu:
             if not names:
                 self._counters.clear()
+                self._deferred.clear()
                 self._gauges.clear()
                 self._hists.clear()
                 return
             for n in names:
                 self._counters.pop(n, None)
+                self._deferred.pop(n, None)
                 self._gauges.pop(n, None)
                 self._hists.pop(n, None)
 

@@ -16,6 +16,13 @@ small batches and at interpreter exit.  ``python -m repro.obs summary``
 renders the tree; ``export --perfetto`` converts to Chrome
 ``trace_event`` JSON.
 
+While collection is on, a :func:`trace` span is also a
+``jax.profiler.TraceAnnotation`` named ``repro.<span name>``, so it lands
+in any active JAX profiler session on the same clock as the device ops.
+JAX is never imported here: the annotation class is looked up once JAX
+is already loaded.  Retrospective :func:`record_span` spans stay out of
+the profiler, which cannot take back-dated events.
+
 Overhead discipline: ``$REPRO_OBS=0`` (or ``set_enabled(False)``) makes
 :func:`trace` return a shared no-op context manager and every helper an
 early-out — no allocation, no lock, no clock read.  Instrumented code
@@ -29,6 +36,7 @@ import functools
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -153,25 +161,47 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
+#: Prefix of a span's name in the JAX profiler's trace.
+ANNOTATION_PREFIX = "repro."
+_annotation_cls = None
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` once JAX is loaded, else None."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
+            return None
+        _annotation_cls = profiler.TraceAnnotation
+    return _annotation_cls
+
 
 class _ActiveSpan:
-    """Context manager wrapping one live Span: pushes the context var on
-    enter, records to the default tracer on exit (error status on
-    exception, which propagates)."""
+    """Context manager wrapping one live Span: pushes the context var and
+    opens the profiler annotation on enter, records to the default tracer
+    on exit (error status on exception, which propagates)."""
 
-    __slots__ = ("span", "_token")
+    __slots__ = ("span", "_token", "_ann")
 
     def __init__(self, span: Span):
         self.span = span
         self._token = None
+        self._ann = None
 
     def __enter__(self) -> Span:
         self._token = _ctx.set((self.span.trace_id, self.span.span_id))
+        cls = _annotation()
+        if cls is not None:
+            self._ann = cls(ANNOTATION_PREFIX + self.span.name)
+            self._ann.__enter__()
         return self.span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         sp = self.span
         sp.t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             sp.status = "error"
             sp.attrs.setdefault("error", exc_type.__name__)

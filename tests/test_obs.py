@@ -120,6 +120,44 @@ def test_decision_spans_parent_under_current_context():
     assert obs.snapshot()["counters"]["tuneish.decisions"] == 1
 
 
+@pytest.mark.parametrize("form", ["with", "decorator"])
+def test_spans_annotate_the_profiler_only_while_enabled(monkeypatch, form):
+    import importlib
+
+    import jax
+
+    trace_mod = importlib.import_module("repro.obs.trace")
+    assert trace_mod._annotation() is jax.profiler.TraceAnnotation
+    opened = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(self.name)
+
+        def __exit__(self, *exc):
+            opened.append("/" + self.name)
+
+    monkeypatch.setattr(trace_mod, "_annotation_cls", Annotation)
+
+    def run(name):
+        if form == "with":
+            with obs.trace(name):
+                with obs.trace("inner"):
+                    pass
+        else:
+            obs.traced(name)(obs.traced("inner")(lambda: None))()
+
+    run("outer")
+    assert opened == ["repro.outer", "repro.inner", "/repro.inner",
+                      "/repro.outer"]
+    obs.set_enabled(False)
+    run("ghost")
+    assert len(opened) == 4
+
+
 # -------------------------------------------------------------- metrics
 
 def test_metrics_registry_counters_gauges_histograms():
@@ -138,6 +176,50 @@ def test_metrics_registry_counters_gauges_histograms():
     reg.reset()
     assert reg.snapshot() == {"counters": {}, "gauges": {},
                               "histograms": {}}
+
+
+def test_deferred_counts_fold_on_the_device_and_read_with_the_counters():
+    import jax.numpy as jnp
+
+    from repro.obs import metrics
+
+    reg = MetricsRegistry()
+    reg.count("c", 1)
+    for v in (3, 4, 5):
+        reg.count_deferred("c", jnp.int32(v), bound=10)
+    assert reg.counter_value("c") == 13
+    assert reg.counters() == {"c": 13}
+    # a sum that could pass int32 is sealed; every sealed sum is read
+    big = metrics.DEVICE_SUM_MAX // 2 + 1
+    for _ in range(5):
+        reg.count_deferred("c", jnp.int32(big), bound=big)
+    assert reg.snapshot()["counters"]["c"] == 13 + 5 * big
+    reg.reset()
+    reg.count_deferred("c", jnp.int32(1), bound=1)
+    reg.reset(["c"])
+    assert reg.counters() == {}
+
+
+def test_a_deferred_add_may_count_into_its_own_registry():
+    """The first device add of a dtype traces, and JAX's trace listener
+    counts into the registry the add folds into, on the same thread."""
+    reg = MetricsRegistry()
+
+    class Scalar(int):
+        def __add__(self, other):
+            reg.count("jit.traces")
+            return Scalar(int(self) + int(other))
+
+    done = threading.Event()
+
+    def add():
+        for _ in range(2):
+            reg.count_deferred("c", Scalar(7), bound=7)
+        done.set()
+
+    threading.Thread(target=add, daemon=True).start()
+    assert done.wait(30), "count_deferred deadlocked"
+    assert reg.counters() == {"c": 14, "jit.traces": 1}
 
 
 def test_latency_histogram_clamps_overflow_and_underflow():
@@ -212,6 +294,97 @@ def test_sampler_counters_account_for_all_edges(rng):
     assert c["sampler.calls"] == 1 and c["sampler.calls.aes"] == 1
     assert c["sampler.edges_dropped"] > 0
     assert c["sampler.edges_kept"] + c["sampler.edges_dropped"] == csr.nnz
+
+
+@pytest.fixture
+def host_reads(monkeypatch):
+    """The shapes of the device arrays read on the host (every
+    ``np.asarray``/``int``/``float``/``bool`` of one goes through
+    ``_value``), while ``reads.on``; the CPU backend ignores
+    ``jax.transfer_guard``."""
+    from jax._src.array import ArrayImpl
+
+    value = ArrayImpl._value
+
+    class Reads(list):
+        on = True
+
+    reads = Reads()
+
+    def counted(self):
+        if reads.on:
+            reads.append(self.shape)
+        return value.fget(self)
+
+    monkeypatch.setattr(ArrayImpl, "_value", property(counted))
+    return reads
+
+
+@pytest.mark.parametrize("backend", ["jax", "pallas"])
+def test_sampling_makes_no_host_read(rng, host_reads, backend):
+    from repro.core.aes_spmm import sample
+
+    csr = random_csr(rng, 64, 8.0, skew=0.8)
+    host_reads.on = True
+    sample(csr, 4, "aes", backend)
+    sample(csr, 4, "aes", backend)
+    assert host_reads == []
+    c = obs.snapshot()["counters"]
+    assert c["sampler.edges_kept"] + c["sampler.edges_dropped"] \
+        == 2 * csr.nnz
+    assert {s.name for s in obs.default_tracer().spans()} == {"sample"}
+
+
+@pytest.mark.parametrize("model,bits", [("gcn", None), ("graphsage", 8)],
+                         ids=["gcn-f32", "graphsage-int8"])
+def test_forward_reads_the_host_only_in_the_requant_guard(
+        rng, host_reads, monkeypatch, model, bits):
+    import jax.numpy as jnp
+
+    from repro.core.quantization import quantize
+    from repro.exec import executor
+    from repro.gnn.models import MODELS, make_sampled_agg
+
+    csr = random_csr(rng, 64, 8.0, skew=0.8)
+    x = jnp.asarray(rng.normal(size=(64, 16)).astype(np.float32))
+    qf = None if bits is None else quantize(x, bits)
+    init, forward, _ = MODELS[model]
+    params = init(np.random.default_rng(0), 16, 8, 3)
+    guard = executor._guarded_requant
+
+    def guarded(*a, **k):
+        host_reads.on = False
+        try:
+            return guard(*a, **k)
+        finally:
+            host_reads.on = True
+
+    monkeypatch.setattr(executor, "_guarded_requant", guarded)
+    forward(params, csr, x, make_sampled_agg(4, "aes", "pallas", qf))
+    assert host_reads == []
+    names = [s.name for s in obs.default_tracer().spans()]
+    assert names.count("sample") == names.count("exec.run_ell") == 2
+    assert names.count("gnn.dense") == 2
+    assert names.count("quant.requant_guard") == (0 if bits is None else 2)
+
+
+def test_jit_traces_count_one_per_new_shape():
+    import jax
+    import jax.numpy as jnp
+
+    from repro.compile_cache import count_compile_events
+
+    count_compile_events()
+    count_compile_events()      # a second call adds no second listener
+    a, b = jnp.ones((3,)), jnp.ones((5, 2))
+    # lax primitives only: a jitted callee would be a jaxpr of its own
+    f = jax.jit(lambda x: jax.lax.sin(x))
+    before = obs.default_registry().counter_value("jit.traces")
+    f(a)
+    f(b)
+    f(b)
+    assert obs.default_registry().counter_value("jit.traces") \
+        == before + 2
 
 
 def test_plan_cache_counters_and_spans(rng):

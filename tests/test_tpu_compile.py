@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,9 +60,10 @@ def spec(topo):
     compilation_cache.reset_cache()
 
 
-def _compile(fn, *args):
+def _compile(fn, *args) -> str:
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 def _operand(spec, feat: int, quant: bool):
@@ -140,6 +142,42 @@ def test_fused_aes_spmm_compiles(spec, feat):
         spec((ROWS,), jnp.int32), spec((ROWS,), jnp.int32),
         spec((pad,), jnp.int32), spec((pad,), jnp.float32),
         _operand(spec, feat, False))
+
+
+@pytest.mark.parametrize("kernel,quant", [("aes_sample", False),
+                                          ("ell_spmm", False),
+                                          ("ell_spmm", True)],
+                         ids=["aes_sample", "ell_spmm-f32", "ell_spmm-uint8"])
+def test_kernel_names_are_pinned(spec, kernel, quant):
+    """The compiled custom-call carries the kernel's own name, which the
+    benchmark's ``sample_ms`` and ``spmm_ms`` select, even when its jitted
+    wrapper is bypassed under another name."""
+    rows, pad = 1024, 1024 + flat_window(W)
+    if kernel == "aes_sample":
+        body = aes_sample.aes_sample.__wrapped__
+
+        def renamed(rs, nz, ci, av):
+            return body(rs, nz, ci, av, sh_width=W, interpret=False)
+
+        args = (spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+                spec((pad,), jnp.int32), spec((pad,), jnp.float32))
+    else:
+        body = ell_spmm.ell_spmm.__wrapped__
+        kw = dict(scale=0.1, x_min=-1.0) if quant else {}
+
+        def renamed(v, c, lw, b):
+            return body(v, c, lw, b, interpret=False, **kw)
+
+        t = jax.eval_shape(tile_features, jax.ShapeDtypeStruct(
+            (rows, LANES), jnp.uint8 if quant else jnp.float32))
+        args = (spec((rows, W), jnp.float32), spec((rows, W), jnp.int32),
+                spec((rows,), jnp.int32),
+                dataclasses.replace(t, tiles=spec(t.tiles.shape,
+                                                  t.tiles.dtype)))
+    text = _compile(renamed, *args)
+    names = re.findall(r"%(\S+) = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert [n.rpartition(".")[0] for n in names] == [kernel]
 
 
 def _widest(fits) -> int:
