@@ -29,6 +29,7 @@ in width-bucketed kernel launches.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -40,22 +41,30 @@ from repro.core.quantization import QuantizedFeatures, dequantize
 from repro.core.sampling import STRATEGIES
 
 
-@jax.jit
-def _edge_counts(val, col, nnz):
+@functools.partial(jax.jit, static_argnames=("sh_width", "by_path"))
+def _edge_counts(val, col, row_ptr, *, sh_width: int, by_path: bool):
     """(kept, dropped) edges of one sampled operand, on the device:
     live slots, and the rest of the ``nnz`` (at least 0, because AES may
-    duplicate hub edges)."""
+    duplicate hub edges).  With ``by_path``, then the rows the Pallas
+    sampler copied whole, sampled, and copied whole with a DMA of their
+    own (``kernels.aes_sample.row_paths``)."""
     kept = ell_live_widths(val, col).sum()
-    return kept, jnp.maximum(nnz - kept, 0)
+    counts = (kept, jnp.maximum(row_ptr[-1] - kept, 0))
+    if by_path:
+        from repro.kernels.aes_sample import row_paths
+
+        counts += row_paths(row_ptr, sh_width)
+    return counts
 
 
 def sample(csr: CSR, sh_width: int, strategy: str = "aes",
            backend: str = "jax") -> ELL:
     """Sampling pre-pass producing the ELL operand."""
     with obs.trace("sample", strategy=strategy, backend=backend):
+        kernel = backend == "pallas" and strategy == "aes"
         if strategy == "full":
             ell = pad_csr_to_ell(csr)
-        elif backend == "pallas" and strategy == "aes":
+        elif kernel:
             from repro.kernels import ops
 
             ell = ops.aes_sample(csr, sh_width)
@@ -65,13 +74,18 @@ def sample(csr: CSR, sh_width: int, strategy: str = "aes",
             ell = ELL(val, col, csr.num_cols)
         if obs.enabled():
             # the paper's accuracy-vs-speed dial, as counters: how many
-            # edges the sampler kept vs. discarded on this call, counted
-            # on the device and read only when the counters are read
-            kept, dropped = _edge_counts(ell.val, ell.col, csr.nnz)
+            # edges the sampler kept vs. discarded on this call, and the
+            # kernel's rows by path, counted on the device and read only
+            # when the counters are read
+            counts = _edge_counts(ell.val, ell.col, csr.row_ptr,
+                                  sh_width=sh_width, by_path=kernel)
             obs.count("sampler.calls")
             obs.count(f"sampler.calls.{strategy}")
-            obs.count_deferred("sampler.edges_kept", kept, csr.nnz)
-            obs.count_deferred("sampler.edges_dropped", dropped, csr.nnz)
+            names = ("edges_kept", "edges_dropped", "rows_whole",
+                     "rows_sampled", "rows_own_dma")
+            bounds = (csr.nnz,) * 2 + (csr.num_rows,) * 3
+            for name, value, bound in zip(names, counts, bounds):
+                obs.count_deferred(f"sampler.{name}", value, bound)
     return ell
 
 

@@ -25,11 +25,10 @@ def ell_fits_smem(width: int, *, sampled: bool = False,
                   block_r: int = 8) -> bool:
     """Whether an ELL operand ``width`` slots wide fits the SMEM of the
     ELL kernels (``ell_spmm``, ``fused_layer_spmm``) at row tile
-    ``block_r`` — and, with ``sampled``, of the ``aes_sample`` kernel that
-    builds it.  A wider one runs on the jax backend only."""
-    need = _aes_sample_mod.smem_bytes(block_r, width) if sampled \
-        else edge_tile_smem_bytes(block_r, width)
-    return need <= SMEM_BUDGET
+    ``block_r`` — and, with ``sampled``, the buffers of the ``aes_sample``
+    kernel that builds it too.  A wider one runs on the jax backend only."""
+    return edge_tile_smem_bytes(block_r, width) <= SMEM_BUDGET and (
+        not sampled or _aes_sample_mod.fits(width))
 
 
 def _interpret_default() -> bool:
@@ -246,34 +245,30 @@ def fused_layer_spmm(ell: ELL, b, w, bias, live_w=None, *, relu: bool = True,
     return out[:rows, :hidden]
 
 
-def aes_sample(csr: CSR, sh_width: int, *, block_r: int = 8,
+def aes_sample(csr: CSR, sh_width: int, *, block_r=None,
                interpret=None) -> ELL:
     """Pallas AES sampling pre-pass: CSR -> ELL(width=sh_width).
 
     Args:
-      csr: source matrix; its ``col_ind``/``val`` are padded by
-        ``flat_window(sh_width)`` trailing elements so the kernel's
-        fixed-size run DMA never over-reads.
+      csr: source matrix.
       sh_width: static ELL width (the paper's shared-memory W knob).
-      block_r: rows per Pallas program (row count padded to a multiple).
+      block_r: rows per Pallas program, a multiple of 8 (default: the
+        kernel's own, ``aes_sample.geometry``).
       interpret: force Pallas interpret mode (default: interpret off-TPU).
+
+    A width whose buffers exceed the kernel's SMEM or VMEM budget is
+    refused with a ``ValueError``.
 
     Returns:
       ``ELL`` with ``val`` f32[num_rows, sh_width], ``col``
       int32[num_rows, sh_width], dead slots zeroed.
     """
     interpret = _interpret_default() if interpret is None else interpret
-    check_smem(_aes_sample_mod.smem_bytes(block_r, sh_width),
-               f"aes_sample at width {sh_width}")
-    rows = csr.num_rows
-    row_start = _pad_to(csr.row_ptr[:-1], block_r, 0)
-    row_nnz = _pad_to(csr.row_nnz(), block_r, 0)
-    ci = jnp.pad(csr.col_ind, (0, flat_window(sh_width)))
-    av = jnp.pad(csr.val, (0, flat_window(sh_width)))
-    val, col = _aes_sample_mod.aes_sample(row_start, row_nnz, ci, av,
-                                          sh_width=sh_width, block_r=block_r,
-                                          interpret=interpret)
-    return ELL(val[:rows], col[:rows], csr.num_cols)
+    _aes_sample_mod.check_fits(sh_width, block_r)
+    val, col = _aes_sample_mod.aes_sample(
+        csr.row_ptr, csr.col_ind, csr.val, sh_width=sh_width,
+        block_r=block_r, interpret=interpret)
+    return ELL(val, col, csr.num_cols)
 
 
 def fused_aes_spmm(csr: CSR, b, sh_width: int, *, block_r: int = 8,

@@ -158,8 +158,9 @@ def tune(csr: CSR, features=None, *, budget: int = 6,
 
     candidates = list(grid) if grid is not None else default_grid(
         widths=widths, backends=backends or _default_backends(), quant=quant)
-    # A pallas candidate whose ELL rows (or aes_sample's tiles) overflow
-    # the kernels' SMEM cannot compile; leave it to the jax backend.
+    # A pallas candidate whose ELL rows overflow the kernels' SMEM (or
+    # aes_sample's SMEM or VMEM) cannot compile; leave it to the jax
+    # backend.
     candidates = [c for c in candidates if c.backend != "pallas"
                   or ops.ell_fits_smem(
                       feats.max_row_nnz if c.strategy == "full"

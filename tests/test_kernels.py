@@ -49,13 +49,63 @@ def test_ell_spmm_dtype_sweep(rng, dtype):
                                atol=1e-2 if dtype == jnp.bfloat16 else 1e-5)
 
 
-@pytest.mark.parametrize("W", [4, 16, 64])
-def test_aes_sample_kernel_matches_jax_sampler(rng, W):
-    g = random_csr(rng, 40, 12.0, skew=0.8)
+def _banded_csr(rng, W: int, rows: int, block_r: int) -> CSR:
+    """Rows of every Table-1 band (nnz 0, 1, W-1, W, W+1, 2W+1, 36W+1,
+    54W+1) among random rows at or under W; row 1 is a hub longer than
+    its block's staged window, so the short rows after it in that block
+    take their own DMA."""
+    from repro.kernels.aes_sample import geometry
+
+    _, window = geometry(rows, W, block_r)
+    deg = rng.integers(0, W + 1, rows)
+    deg[2:6] = [2, W - 1, W, 0]
+    deg[block_r:block_r + 5] = [W + 1, 2 * W + 1, 36 * W + 1, 54 * W + 1, W]
+    # the hub's length puts row 2's two entries across a 1024-entry
+    # boundary; the last band row's puts row block_r + 4 across a lane
+    # tile only
+    deg[1] = window * 128 + 300 + (1023 - deg[0] - window * 128 - 300) % 1024
+    start = deg[:block_r + 3].sum() + 54 * W + 1
+    pad = (126 - start) % 128
+    deg[block_r + 3] += pad + (128 if (start + pad) % 1024 == 1022 else 0)
+    row_ptr = np.concatenate([[0], np.cumsum(deg)]).astype(np.int32)
+    nnz = int(row_ptr[-1])
+    return CSR(jnp.asarray(row_ptr),
+               jnp.asarray(rng.integers(0, rows, nnz).astype(np.int32)),
+               jnp.asarray(rng.normal(size=nnz).astype(np.float32)), rows)
+
+
+@pytest.mark.parametrize("W,block_r,banded", [
+    pytest.param(4, None, False, id="4"),
+    pytest.param(16, None, False, id="16"),
+    pytest.param(64, None, False, id="64"),
+    pytest.param(4, 8, True, id="banded-W4"),
+    pytest.param(16, 16, True, id="banded-W16"),
+    pytest.param(64, 8, True, id="banded-W64"),
+    pytest.param(128, 16, True, id="banded-W128"),
+    pytest.param(256, 8, True, id="banded-W256"),
+])
+def test_aes_sample_kernel_matches_jax_sampler(rng, W, block_r, banded):
+    """The kernel's ELL is ``sample_csr_to_ell``'s, bit for bit.  The
+    banded graphs (44 rows: not a multiple of ``block_r``) hold every
+    Table-1 band, rows after a hub that take their own DMA, and short
+    runs across a 128-lane and a 1024-entry boundary."""
+    from repro.kernels.aes_sample import row_paths
+
+    g = _banded_csr(rng, W, 44, block_r) if banded \
+        else random_csr(rng, 40, 12.0, skew=0.8)
     want_val, want_col = sample_csr_to_ell(g.row_ptr, g.col_ind, g.val, W)
-    got = ops.aes_sample(g, W)
+    got = ops.aes_sample(g, W, block_r=block_r)
     np.testing.assert_array_equal(np.asarray(got.col), np.asarray(want_col))
-    np.testing.assert_allclose(np.asarray(got.val), np.asarray(want_val))
+    np.testing.assert_array_equal(np.asarray(got.val).view(np.int32),
+                                  np.asarray(want_val).view(np.int32))
+    if banded:
+        rp = np.asarray(g.row_ptr)
+        short = (rp[1:] - rp[:-1] <= W) & (rp[1:] - rp[:-1] > 1)
+        lane, flat = (rp[:-1] // t != (rp[1:] - 1) // t
+                      for t in (128, 1024))
+        assert (short & flat).any() and (short & lane & ~flat).any()
+        whole, sampled, own = row_paths(g.row_ptr, W, block_r)
+        assert int(sampled) == 5 and int(whole) == 39 and int(own) >= 4
 
 
 @pytest.mark.parametrize("n,feat,W", [(8, 128, 8), (37, 60, 16), (72, 32, 32)])
@@ -158,9 +208,15 @@ def test_smem_bound_refuses_wide_ell(rng):
         ops.ell_spmm(ell, b)
     with pytest.raises(ValueError, match="SMEM"):
         ops.fused_layer_spmm(ell, b, jnp.ones((16, 4)), jnp.zeros(4))
+    assert not ops.ell_fits_smem(wide, sampled=True)
+    assert ops.ell_fits_smem(wide - 1) \
+        and ops.ell_fits_smem(wide - 1, sampled=True)
+    # aes_sample's own bounds lie far wider: its SMEM holds one sampled
+    # row, its VMEM the window and output tiles of eight rows
+    with pytest.raises(ValueError, match="VMEM"):
+        ops.aes_sample(g, 40_000)
     with pytest.raises(ValueError, match="SMEM"):
-        ops.aes_sample(g, wide)
-    assert ops.ell_fits_smem(wide - 1)
+        ops.aes_sample(g, 1 << 16)
 
     from repro.tuning import PlanCache
     from repro.tuning.autotune import tune

@@ -335,6 +335,33 @@ def test_sampling_makes_no_host_read(rng, host_reads, backend):
     assert {s.name for s in obs.default_tracer().spans()} == {"sample"}
 
 
+def test_sampler_row_counters_count_each_path(host_reads):
+    """On the pallas backend the sampler counts its rows by kernel path,
+    on the device: copied whole (nnz <= W), sampled (nnz > W), and copied
+    whole with a DMA of their own (after a hub longer than the block's
+    staged window, here 2,048 entries).  Counting reads nothing."""
+    import jax.numpy as jnp
+
+    from repro.core.aes_spmm import sample
+    from repro.core.graph import CSR
+
+    deg = np.array([3, 0, 4, 3000, 2, 0, 5, 1, 4, 9] + [1] * 10)
+    row_ptr = np.concatenate([[0], np.cumsum(deg)]).astype(np.int32)
+    nnz = int(row_ptr[-1])
+    csr = CSR(jnp.asarray(row_ptr), jnp.zeros(nnz, jnp.int32),
+              jnp.ones(nnz, jnp.float32), 20)
+    host_reads.on = True
+    sample(csr, 4, "aes", "pallas")
+    sample(csr, 4, "aes", "jax")
+    assert host_reads == []
+    host_reads.on = False
+    c = obs.snapshot()["counters"]
+    assert c["sampler.rows_sampled"] == 3      # 3000, 5, 9
+    assert c["sampler.rows_whole"] == 17       # the empty rows too
+    assert c["sampler.rows_own_dma"] == 13     # 2, 1, 4 and ten 1s
+    assert c["sampler.calls"] == 2
+
+
 @pytest.mark.parametrize("model,bits", [("gcn", None), ("graphsage", 8)],
                          ids=["gcn-f32", "graphsage-int8"])
 def test_forward_reads_the_host_only_in_the_requant_guard(

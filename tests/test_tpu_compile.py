@@ -127,11 +127,10 @@ def test_fused_layer_compiles_at_its_vmem_bound(spec):
 
 
 def test_aes_sample_compiles(spec):
-    pad = NNZ + flat_window(W)
-    _compile(lambda rs, nz, ci, av: aes_sample.aes_sample(
-        rs, nz, ci, av, sh_width=W, interpret=False),
-        spec((ROWS,), jnp.int32), spec((ROWS,), jnp.int32),
-        spec((pad,), jnp.int32), spec((pad,), jnp.float32))
+    _compile(lambda rp, ci, av: aes_sample.aes_sample(
+        rp, ci, av, sh_width=W, interpret=False),
+        spec((ROWS + 1,), jnp.int32), spec((NNZ,), jnp.int32),
+        spec((NNZ,), jnp.float32))
 
 
 @pytest.mark.parametrize("feat", FEATS)
@@ -146,21 +145,34 @@ def test_fused_aes_spmm_compiles(spec, feat):
 
 @pytest.mark.parametrize("kernel,quant", [("aes_sample", False),
                                           ("ell_spmm", False),
-                                          ("ell_spmm", True)],
-                         ids=["aes_sample", "ell_spmm-f32", "ell_spmm-uint8"])
+                                          ("ell_spmm", True),
+                                          ("sampler", False)],
+                         ids=["aes_sample", "ell_spmm-f32", "ell_spmm-uint8",
+                              "sampler"])
 def test_kernel_names_are_pinned(spec, kernel, quant):
     """The compiled custom-call carries the kernel's own name, which the
     benchmark's ``sample_ms`` and ``spmm_ms`` select, even when its jitted
-    wrapper is bypassed under another name."""
+    wrapper is bypassed under another name.  Every Pallas call the
+    program's sampler (``ops.aes_sample``, which ``core.aes_spmm.sample``
+    runs) lowers to is named ``aes_sample``."""
     rows, pad = 1024, 1024 + flat_window(W)
-    if kernel == "aes_sample":
+    if kernel == "sampler":
+        from repro.core.graph import CSR
+
+        def renamed(rp, ci, av):
+            return ops.aes_sample(CSR(rp, ci, av, rows), W, interpret=False)
+
+        kernel = "aes_sample"
+        args = (spec((rows + 1,), jnp.int32), spec((pad,), jnp.int32),
+                spec((pad,), jnp.float32))
+    elif kernel == "aes_sample":
         body = aes_sample.aes_sample.__wrapped__
 
-        def renamed(rs, nz, ci, av):
-            return body(rs, nz, ci, av, sh_width=W, interpret=False)
+        def renamed(rp, ci, av):
+            return body(rp, ci, av, sh_width=W, interpret=False)
 
-        args = (spec((rows,), jnp.int32), spec((rows,), jnp.int32),
-                spec((pad,), jnp.int32), spec((pad,), jnp.float32))
+        args = (spec((rows + 1,), jnp.int32), spec((pad,), jnp.int32),
+                spec((pad,), jnp.float32))
     else:
         body = ell_spmm.ell_spmm.__wrapped__
         kw = dict(scale=0.1, x_min=-1.0) if quant else {}
@@ -194,15 +206,23 @@ def _widest(fits) -> int:
 def test_compiles_at_its_smem_bound(spec, kernel):
     """The widest ELL row the SMEM budget admits compiles for each kernel
     whose SMEM grows with the width; one slot more is refused by the
-    budget, before Mosaic would refuse it."""
-    need = {"ell_spmm": lambda w: edge_tile_smem_bytes(8, w),
-            "fused_layer": lambda w: edge_tile_smem_bytes(8, w),
-            "aes_sample": lambda w: aes_sample.smem_bytes(8, w),
-            "fused_aes_spmm": lambda w: fused_spmm.smem_bytes(8, w)}[kernel]
-    w = _widest(lambda w: need(w) <= SMEM_BUDGET)
-    assert need(w + 1) > SMEM_BUDGET
+    budget, before Mosaic would refuse it.  ``aes_sample`` keeps one
+    sampled row in SMEM and eight rows' windows in VMEM, so its VMEM
+    budget binds first."""
+    fits = {"ell_spmm": lambda w: edge_tile_smem_bytes(8, w) <= SMEM_BUDGET,
+            "fused_layer":
+                lambda w: edge_tile_smem_bytes(8, w) <= SMEM_BUDGET,
+            "aes_sample": aes_sample.fits,
+            "fused_aes_spmm":
+                lambda w: fused_spmm.smem_bytes(8, w) <= SMEM_BUDGET}[kernel]
+    w = _widest(fits)
+    assert not fits(w + 1)
     if kernel in ("ell_spmm", "fused_layer"):
         assert ops.ell_fits_smem(w) and not ops.ell_fits_smem(w + 1)
+    if kernel == "aes_sample":
+        assert aes_sample.smem_bytes(8, w + 1) <= SMEM_BUDGET
+        with pytest.raises(ValueError, match="VMEM"):
+            aes_sample.check_fits(w + 1)
     rows, pad = 4096, 4096 + flat_window(w)
     ell = (spec((rows, w), jnp.float32), spec((rows, w), jnp.int32),
            spec((rows,), jnp.int32))
@@ -217,8 +237,9 @@ def test_compiles_at_its_smem_bound(spec, kernel):
             v, c, lw, x, wt, bias, interpret=False), *ell, b,
             spec((LANES, LANES), jnp.float32), spec((LANES,), jnp.float32))
     elif kernel == "aes_sample":
-        _compile(lambda rs, nz, ci, av: aes_sample.aes_sample(
-            rs, nz, ci, av, sh_width=w, interpret=False), *csr)
+        _compile(lambda rp, ci, av: aes_sample.aes_sample(
+            rp, ci, av, sh_width=w, interpret=False),
+            spec((rows + 1,), jnp.int32), *csr[2:])
     else:
         _compile(lambda rs, nz, ci, av, x: fused_spmm.fused_aes_spmm(
             rs, nz, ci, av, x, sh_width=w, interpret=False), *csr, b)
