@@ -38,7 +38,7 @@ import jax.numpy as jnp
 from repro import obs
 from repro.core.graph import CSR, ELL, ell_live_widths, pad_csr_to_ell
 from repro.core.quantization import QuantizedFeatures, dequantize
-from repro.core.sampling import STRATEGIES
+from repro.core.sampling import BAND_SAMPLES, STRATEGIES, sampled_rows
 
 
 @functools.partial(jax.jit, static_argnames=("sh_width", "by_path"))
@@ -47,14 +47,17 @@ def _edge_counts(val, col, row_ptr, *, sh_width: int, by_path: bool):
     live slots, and the rest of the ``nnz`` (at least 0, because AES may
     duplicate hub edges).  With ``by_path``, then the rows the Pallas
     sampler copied whole, sampled, and copied whole with a DMA of their
-    own (``kernels.aes_sample.row_paths``)."""
+    own (``kernels.aes_sample.row_paths``), the run DMAs of its sampled
+    rows and those rows per Table-1 band
+    (``core.sampling.sampled_rows``)."""
     kept = ell_live_widths(val, col).sum()
     counts = (kept, jnp.maximum(row_ptr[-1] - kept, 0))
     if by_path:
         from repro.kernels.aes_sample import row_paths
 
-        counts += row_paths(row_ptr, sh_width)
-    return counts
+        counts += row_paths(row_ptr, sh_width) \
+            + sampled_rows(row_ptr, sh_width)
+    return jnp.stack(counts)
 
 
 def sample(csr: CSR, sh_width: int, strategy: str = "aes",
@@ -82,10 +85,11 @@ def sample(csr: CSR, sh_width: int, strategy: str = "aes",
             obs.count("sampler.calls")
             obs.count(f"sampler.calls.{strategy}")
             names = ("edges_kept", "edges_dropped", "rows_whole",
-                     "rows_sampled", "rows_own_dma")
-            bounds = (csr.nnz,) * 2 + (csr.num_rows,) * 3
-            for name, value, bound in zip(names, counts, bounds):
-                obs.count_deferred(f"sampler.{name}", value, bound)
+                     "rows_sampled", "rows_own_dma", "sample_runs") \
+                + tuple(f"rows_band{c}" for c in BAND_SAMPLES)
+            obs.count_deferred(
+                tuple(f"sampler.{n}" for n in names[:counts.shape[0]]),
+                counts, max(csr.nnz, max(BAND_SAMPLES) * csr.num_rows))
     return ell
 
 

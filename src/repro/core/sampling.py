@@ -38,6 +38,8 @@ PRIME_NUM = 1429  # paper §3.3: "prime_num is set to 1429"
 _R_THRESHOLDS = (1, 2, 36, 54)
 # (N divisor of W, sample_cnt) for each band above R=1.
 _BANDS = ((4, 4), (8, 8), (16, 16), (32, 32))
+# Samples a row takes in each band above R=1, which names the band.
+BAND_SAMPLES = tuple(c for _, c in _BANDS)
 
 
 class SampleStrategy(NamedTuple):
@@ -73,6 +75,21 @@ def get_sample_strategy(row_nnz: jax.Array, sh_width: int) -> SampleStrategy:
     N = jnp.maximum(N, 1)
     cnt = jnp.minimum(cnt, jnp.maximum(W, 1))
     return SampleStrategy(W=W, N=N, sample_cnt=cnt)
+
+
+def sampled_rows(row_ptr: jax.Array, sh_width: int):
+    """``(runs, *bands)`` of the rows over ``sh_width``, the rows AES
+    samples: their samples (one CSR run each, which the Pallas sampler
+    fetches with a DMA of its own), and those rows in each band of Table 1
+    above R = 1, in the order of :data:`BAND_SAMPLES`.  Traceable."""
+    nnz = row_ptr[1:] - row_ptr[:-1]
+    over = nnz > sh_width
+    runs = jnp.where(over, get_sample_strategy(nnz, sh_width).sample_cnt,
+                     0).sum()
+    band = sum((nnz > t * sh_width).astype(jnp.int32)
+               for t in _R_THRESHOLDS[1:])
+    return (runs,) + tuple((over & (band == k)).sum()
+                           for k in range(len(BAND_SAMPLES)))
 
 
 def hash_start_ind(sample_idx: jax.Array, row_nnz: jax.Array, N: jax.Array) -> jax.Array:

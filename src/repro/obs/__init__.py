@@ -55,11 +55,12 @@ def count(name: str, n: int = 1) -> None:
         default_registry().count(name, n)
 
 
-def count_deferred(name: str, value, bound: int) -> None:
-    """Add a device integer of at most ``bound`` to a counter without
-    reading it (read with the counters) — no-op when disabled."""
+def count_deferred(names: tuple, values, bound: int) -> None:
+    """Add a device integer vector, each element at most ``bound``, to a
+    tuple of counters in one device add, without reading it (read with
+    the counters) — no-op when disabled."""
     if _trace_mod._enabled:
-        default_registry().count_deferred(name, value, bound)
+        default_registry().count_deferred(names, values, bound)
 
 
 def gauge(name: str, value: float) -> None:

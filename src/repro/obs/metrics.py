@@ -152,7 +152,7 @@ class MetricsRegistry:
         # trace listener counts into this registry on the same thread
         self._mu = threading.RLock()
         self._counters: Dict[str, int] = {}
-        self._deferred: Dict[str, _Deferred] = {}
+        self._deferred: Dict[tuple, _Deferred] = {}
         self._gauges: Dict[str, float] = {}
         self._hists: Dict[str, LatencyHistogram] = {}
 
@@ -160,40 +160,42 @@ class MetricsRegistry:
         with self._mu:
             self._counters[name] = self._counters.get(name, 0) + int(n)
 
-    def count_deferred(self, name: str, value, bound: int) -> None:
-        """Add ``value``, a device integer scalar of at most ``bound``, to
-        counter ``name`` without reading it.
+    def count_deferred(self, names: tuple, values, bound: int) -> None:
+        """Add ``values``, a device integer vector of one element per
+        counter in ``names``, each at most ``bound``, to those counters
+        without reading it: one device add serves them all.
 
-        Values fold into one running device sum per counter (``+`` on the
-        device, no sync).  Before the sum could pass
+        Values fold into one running device sum per tuple of counters
+        (``+`` on the device, no sync).  Before the sum could pass
         :data:`DEVICE_SUM_MAX` it is sealed, its copy to the host begun,
         and a fresh sum started; a sealed sum is read at the next seal,
         long after the device wrote it.  Every unread value is read by
         the next :meth:`counter_value`, :meth:`counters` or
         :meth:`snapshot`."""
         with self._mu:
-            d = self._deferred.get(name)
+            d = self._deferred.get(names)
             if d is None:
-                d = self._deferred[name] = _Deferred()
+                d = self._deferred[names] = _Deferred()
             if d.total is not None and bound > d.room:
                 if d.sealed is not None:
-                    self._add_locked(name, d.sealed)
+                    self._add_locked(names, d.sealed)
                 d.sealed, d.total, d.room = d.total, None, DEVICE_SUM_MAX
                 start_copy = getattr(d.sealed, "copy_to_host_async", None)
                 if start_copy is not None:
                     start_copy()
-            d.total = value if d.total is None else d.total + value
+            d.total = values if d.total is None else d.total + values
             d.room -= bound
 
-    def _add_locked(self, name: str, value) -> None:
-        self._counters[name] = self._counters.get(name, 0) + int(value)
+    def _add_locked(self, names: tuple, values) -> None:
+        for name, v in zip(names, values.tolist()):
+            self._counters[name] = self._counters.get(name, 0) + v
 
     def _resolve_locked(self) -> None:
         """Read every deferred value into its counter."""
-        for name, d in self._deferred.items():
-            for value in (d.sealed, d.total):
-                if value is not None:
-                    self._add_locked(name, value)
+        for names, d in self._deferred.items():
+            for values in (d.sealed, d.total):
+                if values is not None:
+                    self._add_locked(names, values)
         self._deferred.clear()
 
     def gauge(self, name: str, value: float) -> None:
@@ -243,7 +245,8 @@ class MetricsRegistry:
         }
 
     def reset(self, names: Iterable[str] = ()) -> None:
-        """Clear everything (or just the named metrics)."""
+        """Clear everything (or just the named metrics; the deferred
+        sums, which may hold other counters too, are read first)."""
         with self._mu:
             if not names:
                 self._counters.clear()
@@ -251,9 +254,9 @@ class MetricsRegistry:
                 self._gauges.clear()
                 self._hists.clear()
                 return
+            self._resolve_locked()
             for n in names:
                 self._counters.pop(n, None)
-                self._deferred.pop(n, None)
                 self._gauges.pop(n, None)
                 self._hists.pop(n, None)
 

@@ -186,18 +186,39 @@ def test_deferred_counts_fold_on_the_device_and_read_with_the_counters():
     reg = MetricsRegistry()
     reg.count("c", 1)
     for v in (3, 4, 5):
-        reg.count_deferred("c", jnp.int32(v), bound=10)
+        reg.count_deferred(("c",), jnp.array([v], jnp.int32), bound=10)
     assert reg.counter_value("c") == 13
     assert reg.counters() == {"c": 13}
     # a sum that could pass int32 is sealed; every sealed sum is read
     big = metrics.DEVICE_SUM_MAX // 2 + 1
     for _ in range(5):
-        reg.count_deferred("c", jnp.int32(big), bound=big)
+        reg.count_deferred(("c",), jnp.array([big], jnp.int32), bound=big)
     assert reg.snapshot()["counters"]["c"] == 13 + 5 * big
     reg.reset()
-    reg.count_deferred("c", jnp.int32(1), bound=1)
+    reg.count_deferred(("c", "d"), jnp.array([1, 2], jnp.int32), bound=2)
     reg.reset(["c"])
-    assert reg.counters() == {}
+    assert reg.counters() == {"d": 2}
+
+
+def test_deferred_counts_of_a_tuple_fold_as_one_vector():
+    """A vector counted into a tuple of counters folds with one device
+    add a call, seals as a whole, and reads into each counter."""
+    import jax.numpy as jnp
+
+    from repro.obs import metrics
+
+    reg = MetricsRegistry()
+    names = ("a", "b", "c")
+    for v in (1, 2):
+        reg.count_deferred(names, jnp.array([v, 10 * v, 0], jnp.int32),
+                           bound=20)
+    assert reg.counters() == {"a": 3, "b": 30, "c": 0}
+    big = metrics.DEVICE_SUM_MAX // 2 + 1
+    for _ in range(3):
+        reg.count_deferred(names, jnp.array([big, 1, 0], jnp.int32),
+                           bound=big)
+    assert reg.snapshot()["counters"] == {"a": 3 + 3 * big, "b": 33,
+                                          "c": 0}
 
 
 def test_a_deferred_add_may_count_into_its_own_registry():
@@ -205,16 +226,19 @@ def test_a_deferred_add_may_count_into_its_own_registry():
     counts into the registry the add folds into, on the same thread."""
     reg = MetricsRegistry()
 
-    class Scalar(int):
+    class Vector(list):
         def __add__(self, other):
             reg.count("jit.traces")
-            return Scalar(int(self) + int(other))
+            return Vector(a + b for a, b in zip(self, other))
+
+        def tolist(self):
+            return list(self)
 
     done = threading.Event()
 
     def add():
         for _ in range(2):
-            reg.count_deferred("c", Scalar(7), bound=7)
+            reg.count_deferred(("c",), Vector([7]), bound=7)
         done.set()
 
     threading.Thread(target=add, daemon=True).start()
@@ -360,6 +384,42 @@ def test_sampler_row_counters_count_each_path(host_reads):
     assert c["sampler.rows_whole"] == 17       # the empty rows too
     assert c["sampler.rows_own_dma"] == 13     # 2, 1, 4 and ten 1s
     assert c["sampler.calls"] == 2
+
+
+def test_sampler_counts_run_dmas_and_rows_per_band(host_reads):
+    """On the pallas backend the sampler also counts, on the device, the
+    run DMAs of the rows over W (one per sample) and those rows per
+    Table-1 band, named by samples per row; on a skewed graph with rows
+    in every band they equal numpy's count over ``row_nnz``.  Counting
+    reads nothing."""
+    import jax.numpy as jnp
+
+    from repro.core.aes_spmm import sample
+    from repro.core.graph import CSR
+
+    w, n = 8, 2000
+    tail = np.random.default_rng(0).pareto(1.6, n) + 0.25
+    deg = np.clip(np.round(tail * 60 / tail.mean()), 1, n - 1).astype(int)
+    row_ptr = np.concatenate([[0], np.cumsum(deg)]).astype(np.int32)
+    nnz = int(row_ptr[-1])
+    csr = CSR(jnp.asarray(row_ptr), jnp.zeros(nnz, jnp.int32),
+              jnp.ones(nnz, jnp.float32), n)
+    host_reads.on = True
+    sample(csr, w, "aes", "pallas")
+    assert host_reads == []
+    host_reads.on = False
+    c = obs.snapshot()["counters"]
+    over = deg > w
+    bounds = [w, 2 * w, 36 * w, 54 * w, n]     # R = 1, 2, 36, 54
+    want = {}
+    for (lo, hi), samples in zip(zip(bounds, bounds[1:]), (4, 8, 16, 32)):
+        rows = int(((deg > lo) & (deg <= hi)).sum())
+        assert rows > 0
+        want[f"sampler.rows_band{samples}"] = rows
+        want["sampler.sample_runs"] = want.get("sampler.sample_runs", 0) \
+            + rows * min(samples, w)
+    assert {k: c[k] for k in want} == want
+    assert c["sampler.rows_sampled"] == over.sum()
 
 
 @pytest.mark.parametrize("model,bits", [("gcn", None), ("graphsage", 8)],

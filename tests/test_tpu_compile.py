@@ -192,6 +192,25 @@ def test_kernel_names_are_pinned(spec, kernel, quant):
     assert [n.rpartition(".")[0] for n in names] == [kernel]
 
 
+def test_sampler_width_is_read_from_its_hlo(spec):
+    """The benchmark's ``sample_roofline`` reads the sampler's width W
+    from its output shape in the op's HLO text, as the trace names it."""
+    from bench import run
+    from repro.core.graph import CSR
+
+    metric = run.load_module(run.BENCH / "metrics" / "sample_roofline.py")
+    rows, pad = 1024, 1024 + flat_window(W)
+    text = _compile(
+        lambda rp, ci, av: ops.aes_sample(CSR(rp, ci, av, rows), W,
+                                          interpret=False),
+        spec((rows + 1,), jnp.int32), spec((pad,), jnp.int32),
+        spec((pad,), jnp.float32))
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    assert metric.OUTPUT.findall(calls[0]) == [str(W)]
+
+
 def _widest(fits) -> int:
     """The largest width ``fits`` admits (``fits`` is monotone)."""
     lo, hi = 1, 1 << 20
