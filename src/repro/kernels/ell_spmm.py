@@ -13,7 +13,10 @@ This is the SpMM stage of Algorithm 1 (lines 16-19), re-thought for TPU:
   * one Pallas program per (row-tile x feature-tile) replaces one CUDA
     thread per output element; the per-row ``k in [0, live_w)`` loop is the
     paper's ``for k <- 0 to W`` with the same dynamic bound
-    ``W = min(row_nnz, sh_width)``.
+    ``W = min(row_nnz, sh_width)``;
+  * where a row spans several lane tiles, one program per row tile
+    instead fetches all of a row's tiles in one DMA and keeps
+    ``RING_DEPTH`` such DMAs in flight, so each edge list is walked once.
 
 A quantized B (a ``TiledFeatures`` with ``bits`` set, on both the
 fixed-width and the block-dispatched kernel) stays packed in HBM and Eq. 2
@@ -35,8 +38,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.gather import (FLAT_TILE, TiledFeatures, flat_window,
                                   for_row_groups, gather_accumulate,
-                                  row_group, smem_words, stage_flat,
-                                  stage_rows, vmem_limit)
+                                  gather_accumulate_ring, row_group,
+                                  smem_words, stage_flat, stage_rows,
+                                  vmem_limit)
 
 
 def block_smem_bytes(block_rows: int, max_w: int) -> int:
@@ -48,24 +52,47 @@ def block_smem_bytes(block_rows: int, max_w: int) -> int:
                 + 2 * (smem_words((1, 2)) + smem_words((1, block_rows))))
 
 
+# Row DMAs the multi-tile gather keeps in flight.  scripts/gather_probe.py
+# on a v5e, ns per tile-row at 5 lane tiles on Reddit's shapes: 18.07 at
+# 4, 9.67 at 8, 7.76 at 16, 7.78 at 32 (two-slot loop 159.22); over every
+# configuration at 2-5 tiles 16 came within 5.6% of the best depth on
+# average, 8 within 12.6%, 32 within 15.0%.
+RING_DEPTH = 16
+
+
+def ring_gather(b: TiledFeatures) -> bool:
+    """Whether :func:`ell_spmm` gathers ``b`` with the ring: only where a
+    row spans two or more lane tiles.  A one-tile row keeps the two-slot
+    loop, which on a one-tile operand was faster than the ring."""
+    return b.tiles.shape[0] > 1
+
+
 def _ell_spmm_kernel(val_ref, col_ref, live_ref, b_ref, out_ref, bsc, sem,
-                     *, pack: int, bits, scale: float, x_min: float):
-    """grid = (row_tiles, feat_tiles).
+                     *, ring: bool, pack: int, bits, scale: float,
+                     x_min: float):
+    """grid = (row_tiles, feat_tiles), or (row_tiles,) with ``ring``.
 
     val_ref:  f32[block_r, W]   SMEM   sampled edge weights
     col_ref:  i32[block_r, W]   SMEM   sampled column indices
     live_ref: i32[1, block_r]   SMEM   live width per row (= min(nnz, W))
     b_ref:    [tiles, num_nodes, block_f] HBM  dense features, f32 or
               quantized ones packed ``pack`` to a word (``TiledFeatures``)
-    out_ref:  f32[block_r, pack * block_f] VMEM
-    bsc:      [2, 1, 1, block_f] VMEM   double-buffered B-row landing zone
-    sem:      DMA semaphores [2]
+    out_ref:  f32[block_r, pack * block_f] VMEM, one feature tile; with
+              ``ring`` f32[block_r, tiles * pack * block_f], the whole row
+    bsc:      [2, 1, 1, block_f] VMEM   double-buffered B-row landing zone;
+              with ``ring`` [RING_DEPTH, tiles, 1, block_f], every tile of
+              a row landing from one DMA
+    sem:      DMA semaphores [2], with ``ring`` [RING_DEPTH]
     """
-    b_tile = b_ref.at[pl.ds(pl.program_id(1), 1)]
+    if ring:
+        b_tile, gather = b_ref, gather_accumulate_ring
+    else:
+        b_tile, gather = b_ref.at[pl.ds(pl.program_id(1), 1)], \
+            gather_accumulate
 
     def row_acc(base, i):
         r = base + i
-        return gather_accumulate(
+        return gather(
             b_tile, bsc, sem, live_ref[0, r], lambda k: col_ref[r, k],
             lambda k: val_ref[r, k], pack=pack, bits=bits, scale=scale,
             x_min=x_min)
@@ -87,40 +114,59 @@ def ell_spmm(ell_val, ell_col, live_w, b: TiledFeatures, *,
     with Eq. 2, ``q * scale + x_min``, and covers ``pack * width``
     features per program.
 
+    A one-tile B runs one program per (row tile x feature tile) with two
+    row DMAs in flight.  Where a row spans several lane tiles
+    (:func:`ring_gather`), one program per row tile fetches every tile of
+    a sampled row in one DMA, ``RING_DEPTH`` of them in flight, and walks
+    each edge list once.  Both sum in the same order.
+
     Returns f32[rows, b.padded_features].
     """
+    return _launch(ell_val, ell_col, live_w, b, ring=ring_gather(b),
+                   block_r=block_r, scale=scale, x_min=x_min,
+                   interpret=interpret)
+
+
+def _launch(ell_val, ell_col, live_w, b: TiledFeatures, *, ring: bool,
+            block_r: int, scale, x_min, interpret: bool):
+    """The ``ell_spmm`` Pallas call, on the ring or on the per-tile grid."""
     rows, width = ell_val.shape
     assert rows % block_r == 0
     block_f, pack = b.width, b.pack
-    tile = pack * block_f
     feat = b.padded_features
     bt = b.tiles
 
-    grid = (rows // block_r, feat // tile)
+    if ring:
+        grid, tile, zone = (rows // block_r,), feat, (
+            RING_DEPTH, bt.shape[0], 1, block_f)
+    else:
+        tile = pack * block_f
+        grid, zone = (rows // block_r, feat // tile), (2, 1, 1, block_f)
     kernel = functools.partial(
-        _ell_spmm_kernel, pack=pack, scale=scale, x_min=x_min, bits=b.bits)
+        _ell_spmm_kernel, ring=ring, pack=pack, scale=scale, x_min=x_min,
+        bits=b.bits)
     return pl.pallas_call(
         kernel,
         name="ell_spmm",
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_r, width), lambda i, j: (i, 0),
+            pl.BlockSpec((block_r, width), lambda i, j=0: (i, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_r, width), lambda i, j: (i, 0),
+            pl.BlockSpec((block_r, width), lambda i, j=0: (i, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((None, 1, block_r), lambda i, j: (i, 0, 0),
+            pl.BlockSpec((None, 1, block_r), lambda i, j=0: (i, 0, 0),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((block_r, tile), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((block_r, tile), lambda i, j=0: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, feat), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((2, 1, 1, block_f), bt.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM(zone, bt.dtype),
+            pltpu.SemaphoreType.DMA((zone[0],)),
         ],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("parallel",) * len(grid)),
     )(ell_val, ell_col,
       live_w.astype(jnp.int32).reshape(rows // block_r, 1, block_r), bt)
 

@@ -1,9 +1,11 @@
 """The gather-accumulate core shared by every SpMM kernel in this package,
 and the one module that knows the layout of the dense operand B.
 
-Each sampled edge ``(val, col)`` of a row pulls one ``[1, width]`` row of
-the dense operand B out of HBM with its own DMA, double-buffered so the
-copy of edge k+1 overlaps the FMA of edge k.  Three Mosaic rules shape it:
+Each sampled edge ``(val, col)`` of a row pulls its row of the dense
+operand B out of HBM with its own DMA, double-buffered so the copy of
+edge k+1 overlaps the FMA of edge k (:func:`gather_accumulate`), or with
+a ring of DMAs in flight (:func:`gather_accumulate_ring`).  Three Mosaic
+rules shape it:
 
   * a DMA address and a loop bound are scalars, and scalars live in
     **SMEM** — every edge list a kernel reads sits in an SMEM block or an
@@ -225,6 +227,40 @@ def stage_flat(pairs, sems, start, n: int):
     return start - base
 
 
+def _row_copy(b_ref, bsc, sem, col_at):
+    """``(k, slot) ->`` the DMA of edge ``k``'s row, every tile of b_ref
+    in one strided copy, into landing slot ``slot`` on its semaphore."""
+    def copy(k, slot):
+        return pltpu.make_async_copy(b_ref.at[:, pl.ds(col_at(k), 1), :],
+                                     bsc.at[slot], sem.at[slot])
+    return copy
+
+
+def _land(bsc, slot, v, acc, *, pack, bits, scale, x_min):
+    """``acc + v * row`` for the row landed in ``slot``, tile by tile,
+    dequantized with Eq. 2 first when ``bits`` is set."""
+    out = []
+    for t in range(bsc.shape[1]):
+        row = bsc[slot, t]
+        if bits is not None:
+            row = dequant_epilogue(unpack_words(row, pack, bits), scale,
+                                   x_min)
+        out.append(acc[t] + v * row.astype(jnp.float32))
+    return tuple(out)
+
+
+def _edge_loop(bsc, live, body, *, pack, bits):
+    """``body(k, acc)`` for ``k < live`` from zero sums, one
+    ``[pack, width]`` sum (``[1, width]`` for a float operand) a tile;
+    returns the sums as ``[1, width]`` pieces in feature order."""
+    _, tiles, _, width = bsc.shape
+    rows = pack if bits is not None else 1
+    acc = jax.lax.fori_loop(
+        0, live, body,
+        tuple(jnp.zeros((rows, width), jnp.float32) for _ in range(tiles)))
+    return [a[i:i + 1, :] for a in acc for i in range(rows)]
+
+
 def gather_accumulate(b_ref, bsc, sem, live, col_at, val_at, *,
                       pack: int = 1, bits=None, scale=1.0, x_min=0.0):
     """``sum_{k < live} val_at(k) * B[col_at(k), :]`` as ``tiles * pack``
@@ -241,11 +277,7 @@ def gather_accumulate(b_ref, bsc, sem, live, col_at, val_at, *,
       live: traced scalar edge count of this row.
       col_at / val_at: ``k -> scalar`` readers of the row's edge list (SMEM).
     """
-    _, tiles, _, width = bsc.shape
-
-    def copy(k, slot):
-        return pltpu.make_async_copy(b_ref.at[:, pl.ds(col_at(k), 1), :],
-                                     bsc.at[slot], sem.at[slot])
+    copy = _row_copy(b_ref, bsc, sem, col_at)
 
     @pl.when(live > 0)
     def _():
@@ -259,21 +291,44 @@ def gather_accumulate(b_ref, bsc, sem, live, col_at, val_at, *,
             copy(k + 1, 1 - slot).start()
 
         copy(k, slot).wait()
-        v = val_at(k)
-        out = []
-        for t in range(tiles):
-            row = bsc[slot, t]
-            if bits is not None:
-                row = dequant_epilogue(unpack_words(row, pack, bits), scale,
-                                       x_min)
-            out.append(acc[t] + v * row.astype(jnp.float32))
-        return tuple(out)
+        return _land(bsc, slot, val_at(k), acc, pack=pack, bits=bits,
+                     scale=scale, x_min=x_min)
 
-    rows = pack if bits is not None else 1
-    acc = jax.lax.fori_loop(
-        0, live, body,
-        tuple(jnp.zeros((rows, width), jnp.float32) for _ in range(tiles)))
-    return [a[i:i + 1, :] for a in acc for i in range(rows)]
+    return _edge_loop(bsc, live, body, pack=pack, bits=bits)
+
+
+def gather_accumulate_ring(b_ref, bsc, sem, live, col_at, val_at, *,
+                           pack: int = 1, bits=None, scale=1.0, x_min=0.0):
+    """:func:`gather_accumulate` with up to ``depth`` row DMAs in flight:
+    ``bsc`` is ``[depth, tiles, 1, width]`` and ``sem`` ``[depth]``.
+
+    Edge ``k`` lands in slot ``k % depth``.  The first ``min(live,
+    depth)`` copies start before the loop; iteration ``k`` waits for its
+    slot, accumulates it, then starts copy ``k + depth`` into the slot it
+    has just read.  The sums run in the same order ``k = 0 .. live - 1``
+    as :func:`gather_accumulate`, so the two agree bit for bit.
+    """
+    depth = bsc.shape[0]
+    copy = _row_copy(b_ref, bsc, sem, col_at)
+
+    for s in range(depth):
+        @pl.when(s < live)
+        def _():
+            copy(s, s).start()
+
+    def body(k, acc):
+        slot = jax.lax.rem(k, depth)
+        copy(k, slot).wait()
+        acc = _land(bsc, slot, val_at(k), acc, pack=pack, bits=bits,
+                    scale=scale, x_min=x_min)
+
+        @pl.when(k + depth < live)
+        def _():
+            copy(k + depth, slot).start()
+
+        return acc
+
+    return _edge_loop(bsc, live, body, pack=pack, bits=bits)
 
 
 def for_row_groups(num_rows: int, row_acc, store_group):

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.graph import CSR, ELL, BlockELL
 
 from . import aes_sample as _aes_sample_mod
@@ -80,6 +81,12 @@ def ell_spmm(ell: ELL, b, live_w=None, *, block_r: int = 8,
     check_smem(edge_tile_smem_bytes(block_r, width),
                f"ell_spmm at width {width}")
     bt = tiled(b, block_f)
+    if obs.enabled():
+        if _ell_mod.ring_gather(bt):
+            obs.count("spmm.ring_calls")
+            obs.count("spmm.ring_tiles", bt.tiles.shape[0])
+        else:
+            obs.count("spmm.tile_calls")
     if live_w is None:
         from repro.core.graph import ell_live_widths
 

@@ -49,6 +49,58 @@ def test_ell_spmm_dtype_sweep(rng, dtype):
                                atol=1e-2 if dtype == jnp.bfloat16 else 1e-5)
 
 
+def _ell_of_widths(rng, live, n: int, W: int):
+    """An ELL operand whose row ``r`` has ``live[r]`` random edges into
+    ``n`` nodes (dead slots zeroed), rows padded to tiles of 8."""
+    rows = -(-len(live) // 8) * 8
+    live = np.pad(np.asarray(live, np.int32), (0, rows - len(live)))
+    col = np.zeros((rows, W), np.int32)
+    val = np.zeros((rows, W), np.float32)
+    for r, k in enumerate(live):
+        col[r, :k] = rng.integers(0, n, k)
+        val[r, :k] = rng.normal(size=k)
+    return jnp.asarray(val), jnp.asarray(col), jnp.asarray(live)
+
+
+@pytest.mark.parametrize("feat,quant,tiles", [
+    pytest.param(200, False, 2, id="f32-2tiles"),
+    pytest.param(300, False, 3, id="f32-3tiles"),
+    pytest.param(500, False, 4, id="f32-4tiles"),
+    pytest.param(602, False, 5, id="f32-5tiles"),
+    pytest.param(600, True, 2, id="uint8-2packed"),
+])
+def test_ring_gather_equals_the_tile_loop_bit_for_bit(rng, feat, quant,
+                                                      tiles):
+    """Where a row spans several lane tiles ``ell_spmm`` gathers it with
+    the DMA ring; its sums equal the per-tile two-slot loop's bit for bit,
+    at live widths 0, 1, D - 1, D, D + 1 and W (D = ``RING_DEPTH``)."""
+    from repro.kernels import ell_spmm
+    from repro.kernels.gather import tile_features
+
+    d, n = ell_spmm.RING_DEPTH, 40
+    W = d + 5
+    val, col, live = _ell_of_widths(rng, [0, 1, d - 1, d, d + 1, W, 3, 0,
+                                          W, d, 2], n, W)
+    if quant:
+        x = rng.integers(0, 256, (n, feat)).astype(np.uint8)
+        kw = dict(scale=0.25, x_min=-3.0)
+    else:
+        x = rng.normal(size=(n, feat)).astype(np.float32)
+        kw = dict(scale=1.0, x_min=0.0)
+    b = tile_features(jnp.asarray(x))
+    assert b.tiles.shape[0] == tiles and ell_spmm.ring_gather(b)
+    got = ell_spmm.ell_spmm(val, col, live, b, **kw)
+    want = ell_spmm._launch(val, col, live, b, ring=False, block_r=8,
+                            interpret=True, **kw)
+    np.testing.assert_array_equal(np.asarray(got).view(np.int32),
+                                  np.asarray(want).view(np.int32))
+    xd = (x * kw["scale"] + kw["x_min"]) if quant else x
+    np.testing.assert_allclose(
+        np.asarray(got)[:, :feat],
+        np.asarray(ref.ell_spmm_rowloop(val, col, jnp.asarray(
+            xd, jnp.float32))), rtol=1e-4, atol=1e-4)
+
+
 def _banded_csr(rng, W: int, rows: int, block_r: int) -> CSR:
     """Rows of every Table-1 band (nnz 0, 1, W-1, W, W+1, 2W+1, 36W+1,
     54W+1) among random rows at or under W; row 1 is a hub longer than

@@ -422,6 +422,29 @@ def test_sampler_counts_run_dmas_and_rows_per_band(host_reads):
     assert c["sampler.rows_sampled"] == over.sum()
 
 
+def test_spmm_counts_which_gather_each_call_takes(rng, host_reads):
+    """``ops.ell_spmm`` counts its gather on the host from the operand's
+    shape: a row of F = 602 spans 5 lane tiles and takes the DMA ring, one
+    of F = 128 the two-slot loop.  Counting reads nothing."""
+    import jax.numpy as jnp
+
+    from repro.core.aes_spmm import sample
+    from repro.exec.executor import PlanExecutor
+
+    csr = random_csr(rng, 24, 4.0, skew=0.8)
+    ell = sample(csr, 4, "aes", "jax")
+    x = {f: jnp.asarray(rng.normal(size=(24, f)).astype(np.float32))
+         for f in (602, 128)}
+    host_reads.on = True
+    for f in (602, 128):
+        PlanExecutor().run_ell(ell, x[f], backend="pallas")
+    assert host_reads == []
+    host_reads.on = False
+    c = obs.snapshot()["counters"]
+    assert (c["spmm.ring_calls"], c["spmm.ring_tiles"],
+            c["spmm.tile_calls"]) == (1, 5, 1)
+
+
 @pytest.mark.parametrize("model,bits", [("gcn", None), ("graphsage", 8)],
                          ids=["gcn-f32", "graphsage-int8"])
 def test_forward_reads_the_host_only_in_the_requant_guard(

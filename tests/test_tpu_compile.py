@@ -88,6 +88,55 @@ def test_ell_spmm_compiles(spec, feat, quant):
         *_ell(spec), _operand(spec, feat, quant))
 
 
+def _mosaic(text: str) -> str:
+    """The Mosaic kernel a compiled program holds, as MLIR text without
+    debug locations."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    body, = re.findall(r'"body":"([^"]+)"', text)
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        return ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+            enable_debug_info=False)
+
+
+@pytest.mark.parametrize("feat,quant,ring", [
+    (128, False, None), (128, True, None), (500, True, None),
+    (640, False, (5, 640)), (500, False, (4, 512)), (1536, True, (3, 1536)),
+], ids=["f32-128", "uint8-128", "uint8-500", "f32-640", "f32-500",
+        "uint8-1536"])
+def test_ell_spmm_takes_the_ring_only_past_one_lane_tile(spec, feat, quant,
+                                                         ring):
+    """The Mosaic kernel ``ell_spmm`` lowers to.  On a one-tile operand
+    (arxiv's F = 128, f32 and uint8, and pubmed's uint8 F = 500) it is the
+    per-tile grid, (row tiles, 1), with a ``[2, 1, 1, 128]`` landing zone
+    and two DMA semaphores.  Past one lane tile the grid is the row tiles
+    alone, the output block the whole padded row, and the landing zone
+    ``[RING_DEPTH, tiles, 1, 128]`` on ``RING_DEPTH`` semaphores."""
+    kw = dict(scale=0.1, x_min=-1.0) if quant else {}
+    body = _mosaic(_compile(lambda v, c, lw, b: ell_spmm.ell_spmm(
+        v, c, lw, b, interpret=False, **kw),
+        *_ell(spec), _operand(spec, feat, quant)))
+    dtype = "i32" if quant else "f32"
+    if ring is None:
+        grid, out, zone, sems = f"{ROWS // 8}, 1", 128 * (4 if feat == 500
+                                                          else 1), \
+            f"2x1x1x128x{dtype}", 2
+    else:
+        d = ell_spmm.RING_DEPTH
+        grid, out, zone, sems = f"{ROWS // 8}", ring[1], \
+            f"{d}x{ring[0]}x1x128x{dtype}", d
+    assert f"iteration_bounds = array<i64: {grid}>" in body
+    assert f"memref<8x{out}xf32, #tpu.memory_space<vmem>>, " \
+        f"memref<{zone}, #tpu.memory_space<vmem>>, " \
+        f"memref<{sems}x!tpu.dma_semaphore" in body
+    assert "scratch_operands = 2" in body
+
+
 @pytest.mark.parametrize("quant", [False, True], ids=["f32", "uint8"])
 @pytest.mark.parametrize("feat", FEATS)
 def test_block_ell_spmm_compiles(spec, feat, quant):
@@ -143,18 +192,20 @@ def test_fused_aes_spmm_compiles(spec, feat):
         _operand(spec, feat, False))
 
 
-@pytest.mark.parametrize("kernel,quant", [("aes_sample", False),
-                                          ("ell_spmm", False),
-                                          ("ell_spmm", True),
-                                          ("sampler", False)],
+@pytest.mark.parametrize("kernel,quant,feat", [("aes_sample", False, 0),
+                                               ("ell_spmm", False, LANES),
+                                               ("ell_spmm", True, LANES),
+                                               ("sampler", False, 0),
+                                               ("ell_spmm", False, 640)],
                          ids=["aes_sample", "ell_spmm-f32", "ell_spmm-uint8",
-                              "sampler"])
-def test_kernel_names_are_pinned(spec, kernel, quant):
+                              "sampler", "ell_spmm-ring-f32"])
+def test_kernel_names_are_pinned(spec, kernel, quant, feat):
     """The compiled custom-call carries the kernel's own name, which the
     benchmark's ``sample_ms`` and ``spmm_ms`` select, even when its jitted
     wrapper is bypassed under another name.  Every Pallas call the
     program's sampler (``ops.aes_sample``, which ``core.aes_spmm.sample``
-    runs) lowers to is named ``aes_sample``."""
+    runs) lowers to is named ``aes_sample``; ``ell_spmm`` keeps its name
+    on the multi-tile ring too."""
     rows, pad = 1024, 1024 + flat_window(W)
     if kernel == "sampler":
         from repro.core.graph import CSR
@@ -181,7 +232,7 @@ def test_kernel_names_are_pinned(spec, kernel, quant):
             return body(v, c, lw, b, interpret=False, **kw)
 
         t = jax.eval_shape(tile_features, jax.ShapeDtypeStruct(
-            (rows, LANES), jnp.uint8 if quant else jnp.float32))
+            (rows, feat), jnp.uint8 if quant else jnp.float32))
         args = (spec((rows, W), jnp.float32), spec((rows, W), jnp.int32),
                 spec((rows,), jnp.int32),
                 dataclasses.replace(t, tiles=spec(t.tiles.shape,
@@ -221,14 +272,17 @@ def _widest(fits) -> int:
 
 
 @pytest.mark.parametrize("kernel", ["ell_spmm", "fused_layer", "aes_sample",
-                                    "fused_aes_spmm"])
+                                    "fused_aes_spmm", "ell_spmm-ring"])
 def test_compiles_at_its_smem_bound(spec, kernel):
     """The widest ELL row the SMEM budget admits compiles for each kernel
     whose SMEM grows with the width; one slot more is refused by the
-    budget, before Mosaic would refuse it.  ``aes_sample`` keeps one
-    sampled row in SMEM and eight rows' windows in VMEM, so its VMEM
-    budget binds first."""
+    budget, before Mosaic would refuse it.  ``ell_spmm`` compiles there
+    on one lane tile and on the multi-tile ring (F = 640), whose SMEM is
+    the same.  ``aes_sample`` keeps one sampled row in SMEM and eight
+    rows' windows in VMEM, so its VMEM budget binds first."""
     fits = {"ell_spmm": lambda w: edge_tile_smem_bytes(8, w) <= SMEM_BUDGET,
+            "ell_spmm-ring":
+                lambda w: edge_tile_smem_bytes(8, w) <= SMEM_BUDGET,
             "fused_layer":
                 lambda w: edge_tile_smem_bytes(8, w) <= SMEM_BUDGET,
             "aes_sample": aes_sample.fits,
@@ -236,7 +290,7 @@ def test_compiles_at_its_smem_bound(spec, kernel):
                 lambda w: fused_spmm.smem_bytes(8, w) <= SMEM_BUDGET}[kernel]
     w = _widest(fits)
     assert not fits(w + 1)
-    if kernel in ("ell_spmm", "fused_layer"):
+    if kernel in ("ell_spmm", "fused_layer", "ell_spmm-ring"):
         assert ops.ell_fits_smem(w) and not ops.ell_fits_smem(w + 1)
     if kernel == "aes_sample":
         assert aes_sample.smem_bytes(8, w + 1) <= SMEM_BUDGET
@@ -248,7 +302,9 @@ def test_compiles_at_its_smem_bound(spec, kernel):
     b = _operand(spec, LANES, False)
     csr = (spec((rows,), jnp.int32), spec((rows,), jnp.int32),
            spec((pad,), jnp.int32), spec((pad,), jnp.float32))
-    if kernel == "ell_spmm":
+    if kernel.startswith("ell_spmm"):
+        if kernel == "ell_spmm-ring":
+            b = _operand(spec, 640, False)
         _compile(lambda v, c, lw, x: ell_spmm.ell_spmm(
             v, c, lw, x, interpret=False), *ell, b)
     elif kernel == "fused_layer":
